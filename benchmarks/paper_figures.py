@@ -360,8 +360,7 @@ def retrieval_scan(batch: int = 8, dim: int = 512, k: int = 8,
     retrieval + routing results) and ``sharded_shrinks_slab``
     (per-device bytes < the unsharded slab).  Requires the backend to
     expose >= mesh devices (``benchmarks.run --mesh-nodes`` forces host
-    devices before jax initialises); shapes whose mesh exceeds the
-    device count are skipped with a note.
+    devices before jax initialises, and fails when it cannot).
 
     Stack-free: runs on synthetic vectors, so CI can smoke it without
     training the diffusion stack."""
@@ -416,13 +415,6 @@ def retrieval_scan(batch: int = 8, dim: int = 512, k: int = 8,
             for m in C.MESH_NODES:
                 if m <= 1:
                     continue
-                import jax
-                if len(jax.devices()) < m:
-                    mesh_rows.append({
-                        "nodes": n_nodes, "capacity": cap, "mesh_nodes": m,
-                        "skipped": f"backend has {len(jax.devices())} "
-                                   f"devices < mesh {m}"})
-                    continue
                 # identical second fleet: the first one's dbs are bound
                 # to the unsharded index (both would receive updates)
                 rng2 = np.random.default_rng(1000 * n_nodes + cap)
@@ -451,7 +443,6 @@ def retrieval_scan(batch: int = 8, dim: int = 512, k: int = 8,
                     "sharded_parity_ok": parity,
                 })
     wins = [r for r in rows if r["nodes"] >= 4 and r["capacity"] >= 2048]
-    ran_mesh = [r for r in mesh_rows if "skipped" not in r]
     return {"rows": rows, "mesh_rows": mesh_rows,
             "fused_beats_loop_everywhere":
                 all(r["speedup"] > 1.0 for r in rows),
@@ -460,12 +451,12 @@ def retrieval_scan(batch: int = 8, dim: int = 512, k: int = 8,
                 all(r["speedup"] > 1.0 for r in wins) if wins else None,
             # sharded-arm gates: None when no mesh>1 arm ran
             "sharded_parity_ok":
-                all(r["sharded_parity_ok"] for r in ran_mesh)
-                if ran_mesh else None,
+                all(r["sharded_parity_ok"] for r in mesh_rows)
+                if mesh_rows else None,
             "sharded_shrinks_slab":
                 all(r["per_device_slab_bytes"]
-                    < r["single_device_slab_bytes"] for r in ran_mesh)
-                if ran_mesh else None}
+                    < r["single_device_slab_bytes"] for r in mesh_rows)
+                if mesh_rows else None}
 
 
 # ---------------------------------------------------------------------------

@@ -138,14 +138,16 @@ def main() -> int:
         ap.error("--crash-at must be in (0, 1)")
     if args.corrupt_frac is not None and not 0.0 < args.corrupt_frac <= 1.0:
         ap.error("--corrupt-frac must be in (0, 1]")
+    from repro.launch.mesh import enable_compile_cache, ensure_host_devices
     if args.mesh_nodes and max(args.mesh_nodes) > 1:
         # must land before the benchmark imports below can initialise
         # the XLA backend — host-device forcing is a no-op afterwards
-        from repro.launch.mesh import ensure_host_devices
-        if not ensure_host_devices(max(args.mesh_nodes)):
-            print(f"# warning: backend already up with fewer than "
-                  f"{max(args.mesh_nodes)} devices; sharded arms will "
-                  "be skipped")
+        ensure_host_devices(max(args.mesh_nodes))
+        import jax
+        if len(jax.devices()) < max(args.mesh_nodes):
+            ap.error(f"--mesh-nodes {max(args.mesh_nodes)} needs that many "
+                     f"devices, backend has {len(jax.devices())}")
+    enable_compile_cache()
 
     from benchmarks.paper_figures import ALL_BENCHMARKS, STACK_FREE
     from benchmarks import common as C

@@ -100,14 +100,15 @@ class BlobStore:
 @partial(jax.jit, static_argnames=("k",))
 def _masked_topk(query, db, valid, k: int):
     """Cosine top-k of `query` (d,) against `db` (cap, d) under mask."""
-    scores = db @ query  # vectors are L2-normalised at insert
+    # vectors are L2-normalised at insert; full f32 on every backend
+    scores = jnp.matmul(db, query, precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(valid, scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 
 
 @partial(jax.jit, static_argnames=("k",))
 def _masked_topk_batch(queries, db, valid, k: int):
-    scores = queries @ db.T
+    scores = jnp.matmul(queries, db.T, precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 

@@ -1,18 +1,21 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Pallas TPU kernels + version-compat shims.
+"""Pallas TPU kernels, their jnp reference oracles (``ref.py``) and the
+public entry points (``ops.py``).
 
-JAX renamed ``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams`` across
-releases; the installed version may carry either name.  Every kernel in
-this package imports :data:`CompilerParams` from here so the rename never
-breaks the suite again.
+Every kernel takes ``interpret: Optional[bool] = None``; ``None`` resolves
+by backend through :func:`resolve_interpret`, so a kernel compiles through
+Mosaic on a TPU and runs the same body in interpret mode elsewhere.
 """
-from jax.experimental.pallas import tpu as _pltpu
+from typing import Optional
 
-try:  # newer JAX
-    CompilerParams = _pltpu.CompilerParams
-except AttributeError:  # older JAX (e.g. 0.4.x)
-    CompilerParams = _pltpu.TPUCompilerParams
+import jax
+from jax.experimental.pallas.tpu import CompilerParams
 
-__all__ = ["CompilerParams"]
+__all__ = ["CompilerParams", "resolve_interpret"]
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Backend-aware interpret default: only interpret when no TPU/Mosaic
+    backend is available to compile the kernel."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

@@ -8,13 +8,13 @@ step, per-row statistics in-register.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
+from repro.kernels import CompilerParams, resolve_interpret
 
 
 def _adaln_kernel(x_ref, shift_ref, scale_ref, o_ref, *, eps: float):
@@ -29,7 +29,7 @@ def _adaln_kernel(x_ref, shift_ref, scale_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("block_t", "eps", "interpret"))
 def adaln_modulate(x, shift, scale, *, block_t: int = 256, eps: float = 1e-5,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """x: (B, T, D); shift/scale: (B, D) → (B, T, D)."""
     b, t, d = x.shape
     block_t = min(block_t, t)
@@ -50,6 +50,6 @@ def adaln_modulate(x, shift, scale, *, block_t: int = 256, eps: float = 1e-5,
         out_shape=jax.ShapeDtypeStruct((b, t + pad_t, d), x.dtype),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, shift.reshape(b, 1, d), scale.reshape(b, 1, d))
     return out[:, :t]

@@ -17,13 +17,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
+from repro.kernels import CompilerParams, resolve_interpret
 
 NEG_INF = -1e30
 
@@ -80,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: Optional[bool] = None):
     """q: (B, Sq, H, D); k/v: (B, Sk, H, D) → (B, Sq, H, D).
 
     Sequence lengths are padded to the block size internally; D should be a
@@ -128,7 +129,7 @@ def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     out = out[:, :, :sq]
     return jnp.moveaxis(out, 1, 2)

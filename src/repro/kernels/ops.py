@@ -1,55 +1,14 @@
-"""Jit'd public wrappers for the Pallas kernels with backend dispatch.
+"""Public entry points for the model-side Pallas kernels.
 
-On TPU the compiled kernels run natively; elsewhere (this CPU container,
-unit tests) they execute in interpret mode, which runs the *same kernel
-body* in Python-on-XLA for bit-accurate validation against ``ref.py``.
+Each kernel resolves ``interpret=None`` by backend (see
+:func:`repro.kernels.resolve_interpret`): compiled through Mosaic on a
+TPU, the same kernel body in interpret mode elsewhere (unit tests on the
+CPU), validated against ``ref.py``.
 """
 from __future__ import annotations
 
-from repro.kernels import ref
-from repro.kernels.adaln import adaln_modulate as _adaln_pallas
-from repro.kernels.flash_attention import flash_attention as _flash_pallas
-from repro.kernels.groupnorm_silu import groupnorm_silu as _gn_pallas
-from repro.kernels.vdb_topk import vdb_topk as _vdb_pallas
-from repro.kernels.vdb_topk import vdb_topk_sharded as _vdb_sharded_pallas
+from repro.kernels.adaln import adaln_modulate
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.groupnorm_silu import groupnorm_silu
 
-
-def _interpret() -> bool:
-    # single source of truth for the backend-aware interpret rule
-    from repro.kernels.vdb_topk import resolve_interpret
-    return resolve_interpret(None)
-
-
-def flash_attention(q, k, v, *, causal: bool = False, block_q: int = 128,
-                    block_k: int = 128):
-    return _flash_pallas(q, k, v, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=_interpret())
-
-
-def vdb_topk(queries, db, valid, k: int, *, block_n: int = 512):
-    return _vdb_pallas(queries, db, valid, k, block_n=block_n,
-                       interpret=_interpret())
-
-
-def vdb_topk_sharded(queries, slabs, valid, node_ids, k: int, *,
-                     block_n: int = 512, mask_nodes: bool = True):
-    return _vdb_sharded_pallas(queries, slabs, valid, node_ids, k,
-                               block_n=block_n, mask_nodes=mask_nodes,
-                               interpret=_interpret())
-
-
-def groupnorm_silu(x, scale, bias, *, groups: int = 32):
-    return _gn_pallas(x, scale, bias, groups=groups, interpret=_interpret())
-
-
-def adaln_modulate(x, shift, scale, *, block_t: int = 256):
-    return _adaln_pallas(x, shift, scale, block_t=block_t,
-                         interpret=_interpret())
-
-
-# re-export oracles for convenience
-flash_attention_ref = ref.flash_attention_ref
-vdb_topk_ref = ref.vdb_topk_ref
-vdb_topk_sharded_ref = ref.vdb_topk_sharded_ref
-groupnorm_silu_ref = ref.groupnorm_silu_ref
-adaln_modulate_ref = ref.adaln_modulate_ref
+__all__ = ["adaln_modulate", "flash_attention", "groupnorm_silu"]

@@ -5,6 +5,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# retrieval scores at full float32 precision on every backend (a TPU's
+# default f32 matmul is one bf16 pass), matching the Pallas scans
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def flash_attention_ref(q, k, v, *, causal: bool = False):
     """q: (B, Sq, H, D); k/v: (B, Sk, H, D) -> (B, Sq, H, D)."""
@@ -21,7 +25,7 @@ def flash_attention_ref(q, k, v, *, causal: bool = False):
 def vdb_topk_ref(queries, db, valid, k: int):
     """queries: (Q, D) L2-normalised; db: (N, D); valid: (N,) bool.
     Returns (scores (Q, k), idx (Q, k)) by cosine similarity."""
-    scores = queries @ db.T
+    scores = jnp.matmul(queries, db.T, precision=_HIGHEST)
     scores = jnp.where(valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
 
@@ -40,7 +44,7 @@ def vdb_topk_sharded_ref(queries, slabs, valid, node_ids, k: int, *,
     ``jax.lax.top_k`` — the ordering contract the cross-shard merge
     reproduces."""
     n_idx, n_nodes, cap, _ = slabs.shape
-    scores = jnp.einsum("qd,incd->iqnc", queries, slabs)
+    scores = jnp.einsum("qd,incd->iqnc", queries, slabs, precision=_HIGHEST)
     ok = valid[None, None, :, :]
     if mask_nodes:
         ok = ok & (node_ids[None, :, None, None]
@@ -61,7 +65,7 @@ def vdb_topk_pernode_ref(queries, slabs, valid, k: int):
     on the node axis (the mesh-sharded scan runs it per device on the
     local shard; per-node results need no cross-shard merge)."""
     n_idx, n_nodes, cap, _ = slabs.shape
-    scores = jnp.einsum("qd,incd->inqc", queries, slabs)
+    scores = jnp.einsum("qd,incd->inqc", queries, slabs, precision=_HIGHEST)
     scores = jnp.where(valid[None, :, None, :], scores, -jnp.inf)
     s, col = jax.lax.top_k(scores, k)
     gidx = col + (jnp.arange(n_nodes) * cap)[None, :, None, None]

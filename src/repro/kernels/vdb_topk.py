@@ -55,17 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
+from repro.kernels import CompilerParams, resolve_interpret
 
 NEG_INF = -1e30
-
-
-def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Backend-aware interpret default: only interpret when no TPU/Mosaic
-    backend is available to compile the kernel."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
 
 
 def _vdb_kernel(q_ref, db_ref, valid_ref, score_out, idx_out,
@@ -82,8 +74,7 @@ def _vdb_kernel(q_ref, db_ref, valid_ref, score_out, idx_out,
     db = db_ref[...].astype(jnp.float32)         # (block_n, D)
     valid = valid_ref[...]                       # (1, block_n) int32
 
-    s = jax.lax.dot_general(q, db, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, block_n)
+    s = _scores(q, db)                           # (Q, block_n)
     cols = ni * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     ok = (valid > 0) & (cols < n_total)
     s = jnp.where(ok, s, NEG_INF)
@@ -95,25 +86,54 @@ def _vdb_kernel(q_ref, db_ref, valid_ref, score_out, idx_out,
         idx_out[...] = best_i[...]
 
 
+def _scores(q, db):
+    """Similarity tile at full float32 precision (a TPU's default f32
+    matmul is one bf16 pass, ~1e-3 off for unit vectors)."""
+    return jax.lax.dot_general(q, db, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _merge_topk(best_s, best_i, s, cand_cols, k: int) -> None:
     """Merge one similarity tile into the running top-k: k rounds of
-    max+mask over the concatenated (k + block_n) candidates."""
+    max+mask over the concatenated (k + block_n) candidates.
+
+    Each round picks the FIRST position holding the row max (a min over
+    a position iota — Mosaic has no cumsum).  Candidates arrive in
+    ascending slot order and the running top-k sits in front of the
+    tile, so the first position is the lowest global slot: ties resolve
+    exactly like ``jax.lax.top_k`` in the oracles."""
     cand_s = jnp.concatenate([best_s[...], s], axis=1)          # (Q, k+bn)
     cand_i = jnp.concatenate([best_i[...], cand_cols], axis=1)
+    width = cand_s.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, best_s.shape, 1)
     new_s = jnp.zeros_like(best_s[...])
     new_i = jnp.zeros_like(best_i[...])
     for j in range(k):
         m = jnp.max(cand_s, axis=1, keepdims=True)              # (Q, 1)
-        # first position achieving the max
-        is_max = cand_s == m
-        first = jnp.cumsum(is_max.astype(jnp.int32), axis=1) == 1
-        pick = is_max & first
+        first = jnp.min(jnp.where(cand_s == m, pos, width), axis=1,
+                        keepdims=True)
+        pick = pos == first
         picked_i = jnp.sum(jnp.where(pick, cand_i, 0), axis=1, keepdims=True)
-        new_s = jax.lax.dynamic_update_slice(new_s, m, (0, j))
-        new_i = jax.lax.dynamic_update_slice(new_i, picked_i, (0, j))
+        new_s = jnp.where(lane == j, m, new_s)
+        new_i = jnp.where(lane == j, picked_i, new_i)
         cand_s = jnp.where(pick, NEG_INF, cand_s)
     best_s[...] = new_s
     best_i[...] = new_i
+
+
+_LANE = 128
+
+
+def _lane_blocks(n: int, block_n: int):
+    """Block size and padded length for a scan over ``n`` rows: the block
+    is a multiple of the 128-lane width (the TPU block rule for the last
+    dimension of the validity row) and the padded length a multiple of
+    the block.  Returns ``(block_n, n_padded)``."""
+    n_lane = -(-n // _LANE) * _LANE
+    block_n = min(-(-block_n // _LANE) * _LANE, n_lane)
+    return block_n, -(-n // block_n) * block_n
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -123,12 +143,10 @@ def vdb_topk(queries, db, valid, k: int, *, block_n: int = 512,
     interpret = resolve_interpret(interpret)
     qn, d = queries.shape
     n = db.shape[0]
-    block_n = min(block_n, n)
-    pad_n = (-n) % block_n
-    if pad_n:
-        db = jnp.pad(db, ((0, pad_n), (0, 0)))
-        valid = jnp.pad(valid, (0, pad_n))
-    n_p = n + pad_n
+    block_n, n_p = _lane_blocks(n, block_n)
+    if n_p != n:
+        db = jnp.pad(db, ((0, n_p - n), (0, 0)))
+        valid = jnp.pad(valid, (0, n_p - n))
     n_blocks = n_p // block_n
     valid_i = valid.astype(jnp.int32).reshape(1, n_p)
 
@@ -164,7 +182,7 @@ def vdb_topk(queries, db, valid, k: int, *, block_n: int = 512,
 def _vdb_sharded_kernel(q_ref, slab_ref, valid_ref, nid_ref, score_out,
                         idx_out, best_s, best_i, *, k: int, block_n: int,
                         n_blocks: int, n_nodes: int, capacity: int,
-                        mask_nodes: bool, per_node: bool = False):
+                        mask_nodes: bool, per_node: bool):
     """Shared body of the cluster scan.  ``per_node=False`` keeps ONE
     running top-k across the whole (node, block) sweep of an index plane
     (global candidate list, optional query→node mask); ``per_node=True``
@@ -182,15 +200,13 @@ def _vdb_sharded_kernel(q_ref, slab_ref, valid_ref, nid_ref, score_out,
 
     q = q_ref[...].astype(jnp.float32)           # (Q, D)
     db = slab_ref[0, 0].astype(jnp.float32)      # (block_n, D)
-    valid = valid_ref[...]                       # (1, block_n) int32
+    valid = valid_ref[0]                         # (1, block_n) int32
 
-    s = jax.lax.dot_general(q, db, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, bn)
+    s = _scores(q, db)                           # (Q, bn)
     cols = bi * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     ok = (valid > 0) & (cols < capacity)
     if mask_nodes and not per_node:
-        nid = nid_ref[...]                       # (1, Q) int32
-        ok = ok & (nid.reshape(-1, 1) == ni)     # query sees only its node
+        ok = ok & (nid_ref[...] == ni)           # (Q, 1): query sees its node
     s = jnp.where(ok, s, NEG_INF)
     _merge_topk(best_s, best_i, s, ni * capacity + cols, k)
 
@@ -199,9 +215,65 @@ def _vdb_sharded_kernel(q_ref, slab_ref, valid_ref, nid_ref, score_out,
 
     @pl.when(done)
     def _finalize():
-        score_out[...] = best_s[...].reshape(score_out.shape) \
-            .astype(score_out.dtype)
-        idx_out[...] = best_i[...].reshape(idx_out.shape)
+        if per_node:
+            score_out[0, 0] = best_s[...].astype(score_out.dtype)
+            idx_out[0, 0] = best_i[...]
+        else:
+            score_out[0] = best_s[...].astype(score_out.dtype)
+            idx_out[0] = best_i[...]
+
+
+def _cluster_scan(queries, slabs, valid, node_ids, k: int, *, block_n: int,
+                  mask_nodes: bool, per_node: bool, interpret: bool):
+    """One ``pallas_call`` over grid ``(index, node, db_block)`` for both
+    cluster scan modes.  Capacity is padded to a lane-multiple block;
+    validity rides as ``(nodes, 1, capacity)`` and node ids as ``(Q, 1)``
+    so every block obeys the TPU (8, 128) rule."""
+    n_idx, n_nodes, cap, d = slabs.shape
+    qn = queries.shape[0]
+    block_n, cap_p = _lane_blocks(cap, block_n)
+    if cap_p != cap:
+        slabs = jnp.pad(slabs, ((0, 0), (0, 0), (0, cap_p - cap), (0, 0)))
+        valid = jnp.pad(valid, ((0, 0), (0, cap_p - cap)))
+    n_blocks = cap_p // block_n
+    valid_i = valid.astype(jnp.int32).reshape(n_nodes, 1, cap_p)
+    nid = node_ids.astype(jnp.int32).reshape(qn, 1)
+
+    kernel = functools.partial(_vdb_sharded_kernel, k=k, block_n=block_n,
+                               n_blocks=n_blocks, n_nodes=n_nodes,
+                               capacity=cap, mask_nodes=mask_nodes,
+                               per_node=per_node)
+    if per_node:
+        out_block = (1, 1, qn, k)
+        out_index = lambda ii, ni, bi: (ii, ni, 0, 0)  # noqa: E731
+        out_dims = (n_idx, n_nodes, qn, k)
+    else:
+        out_block = (1, qn, k)
+        out_index = lambda ii, ni, bi: (ii, 0, 0)  # noqa: E731
+        out_dims = (n_idx, qn, k)
+    return pl.pallas_call(
+        kernel,
+        grid=(n_idx, n_nodes, n_blocks),
+        in_specs=[
+            pl.BlockSpec((qn, d), lambda ii, ni, bi: (0, 0)),
+            pl.BlockSpec((1, 1, block_n, d),
+                         lambda ii, ni, bi: (ii, ni, bi, 0)),
+            pl.BlockSpec((1, 1, block_n), lambda ii, ni, bi: (ni, 0, bi)),
+            pl.BlockSpec((qn, 1), lambda ii, ni, bi: (0, 0)),
+        ],
+        out_specs=[pl.BlockSpec(out_block, out_index)] * 2,
+        out_shape=[
+            jax.ShapeDtypeStruct(out_dims, jnp.float32),
+            jax.ShapeDtypeStruct(out_dims, jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((qn, k), jnp.float32),
+            pltpu.VMEM((qn, k), jnp.int32),
+        ],
+        compiler_params=CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(queries, slabs, valid_i, nid)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "mask_nodes",
@@ -228,49 +300,10 @@ def vdb_topk_sharded(queries, slabs, valid, node_ids, k: int, *,
     scan stays memory-bound at ~n_idx·nodes·capacity·D·dtype bytes
     regardless of node count.
     """
-    interpret = resolve_interpret(interpret)
-    n_idx, n_nodes, cap, d = slabs.shape
-    qn = queries.shape[0]
-    block_n = min(block_n, cap)
-    pad_c = (-cap) % block_n
-    if pad_c:
-        slabs = jnp.pad(slabs, ((0, 0), (0, 0), (0, pad_c), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad_c)))
-    cap_p = cap + pad_c
-    n_blocks = cap_p // block_n
-    valid_i = valid.astype(jnp.int32)
-    nid = node_ids.astype(jnp.int32).reshape(1, qn)
-
-    kernel = functools.partial(_vdb_sharded_kernel, k=k, block_n=block_n,
-                               n_blocks=n_blocks, n_nodes=n_nodes,
-                               capacity=cap, mask_nodes=mask_nodes)
-    scores, idx = pl.pallas_call(
-        kernel,
-        grid=(n_idx, n_nodes, n_blocks),
-        in_specs=[
-            pl.BlockSpec((qn, d), lambda ii, ni, bi: (0, 0)),
-            pl.BlockSpec((1, 1, block_n, d),
-                         lambda ii, ni, bi: (ii, ni, bi, 0)),
-            pl.BlockSpec((1, block_n), lambda ii, ni, bi: (ni, bi)),
-            pl.BlockSpec((1, qn), lambda ii, ni, bi: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, qn, k), lambda ii, ni, bi: (ii, 0, 0)),
-            pl.BlockSpec((1, qn, k), lambda ii, ni, bi: (ii, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_idx, qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_idx, qn, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qn, k), jnp.float32),
-            pltpu.VMEM((qn, k), jnp.int32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(queries, slabs, valid_i, nid)
-    return scores, idx
+    return _cluster_scan(queries, slabs, valid, node_ids, k,
+                         block_n=block_n, mask_nodes=mask_nodes,
+                         per_node=False,
+                         interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -294,50 +327,11 @@ def vdb_topk_pernode(queries, slabs, valid, k: int, *,
     score-aware scheduling (per-node best match for every request) and
     the chosen node's retrieval candidates.
     """
-    interpret = resolve_interpret(interpret)
-    n_idx, n_nodes, cap, d = slabs.shape
     qn = queries.shape[0]
-    block_n = min(block_n, cap)
-    pad_c = (-cap) % block_n
-    if pad_c:
-        slabs = jnp.pad(slabs, ((0, 0), (0, 0), (0, pad_c), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad_c)))
-    cap_p = cap + pad_c
-    n_blocks = cap_p // block_n
-    valid_i = valid.astype(jnp.int32)
-    nid = jnp.zeros((1, qn), jnp.int32)          # unused in per-node mode
-
-    kernel = functools.partial(_vdb_sharded_kernel, k=k, block_n=block_n,
-                               n_blocks=n_blocks, n_nodes=n_nodes,
-                               capacity=cap, mask_nodes=False,
-                               per_node=True)
-    scores, idx = pl.pallas_call(
-        kernel,
-        grid=(n_idx, n_nodes, n_blocks),
-        in_specs=[
-            pl.BlockSpec((qn, d), lambda ii, ni, bi: (0, 0)),
-            pl.BlockSpec((1, 1, block_n, d),
-                         lambda ii, ni, bi: (ii, ni, bi, 0)),
-            pl.BlockSpec((1, block_n), lambda ii, ni, bi: (ni, bi)),
-            pl.BlockSpec((1, qn), lambda ii, ni, bi: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, qn, k), lambda ii, ni, bi: (ii, ni, 0, 0)),
-            pl.BlockSpec((1, 1, qn, k), lambda ii, ni, bi: (ii, ni, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_idx, n_nodes, qn, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_idx, n_nodes, qn, k), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qn, k), jnp.float32),
-            pltpu.VMEM((qn, k), jnp.int32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(queries, slabs, valid_i, nid)
-    return scores, idx
+    return _cluster_scan(queries, slabs, valid, jnp.zeros((qn,), jnp.int32),
+                         k, block_n=block_n, mask_nodes=False,
+                         per_node=True,
+                         interpret=resolve_interpret(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -353,12 +347,11 @@ def _mesh_scan_fn(mesh, n_shard: int, capacity: int, k: int,
     configuration.  Each device runs the unmodified single-device scan —
     Pallas kernel or jnp ref — over its LOCAL ``(n_idx, n_shard,
     capacity, dim)`` slab shard, then globalises the slot ids by its
-    shard offset.  ``check_rep=False`` because ``pallas_call`` has no
-    replication rule; every output here is explicitly sharded anyway.
+    shard offset.  ``check_vma=False`` because ``pallas_call`` has no
+    varying-axes rule; every output here is explicitly sharded anyway.
 
     Cache note: keying on the hashable ``Mesh`` keeps one executable per
     (mesh, shape, mode) across ClusterIndex rebuilds/restacks."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(slabs_l, valid_l, queries, node_ids):
@@ -389,11 +382,11 @@ def _mesh_scan_fn(mesh, n_shard: int, capacity: int, k: int,
 
     out_specs = ((P(None, "nodes", None, None),) * 2 if per_node
                  else (P("nodes", None, None, None),) * 2)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "nodes", None, None), P("nodes", None),
                   P(None, None), P(None)),
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

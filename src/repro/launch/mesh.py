@@ -13,9 +13,11 @@ inter-pod DCI bandwidth ≪ intra-pod ICI).
 from __future__ import annotations
 
 import os
-import re
 
 import jax
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,10 +39,15 @@ def make_node_mesh(mesh_nodes: int):
     """1-D ``("nodes",)`` mesh for the sharded cluster-retrieval scans
     (core/cluster_index.py): the embarrassingly-parallel node axis of the
     stacked cache slabs maps one shard of nodes per device.  Raises
-    ``ValueError`` when the backend has fewer devices than requested —
-    callers that want graceful degradation (tests, CLI) check
-    ``len(jax.devices())`` first or force host devices with
-    :func:`ensure_host_devices`."""
+    ``ValueError`` when the backend has fewer devices than requested.
+
+    The axis is ``Auto``: the donated ``.at[node, slots].set`` row
+    updates are plain jitted scatters, and sharding propagation routes
+    each write to the owning shard.  (``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which those scatters would need an
+    ``out_sharding`` at every call site.)"""
+    from jax.sharding import AxisType
+
     if mesh_nodes < 1:
         raise ValueError(f"mesh_nodes must be >= 1, got {mesh_nodes}")
     avail = len(jax.devices())
@@ -49,27 +56,36 @@ def make_node_mesh(mesh_nodes: int):
             f"mesh_nodes={mesh_nodes} needs that many devices, backend has "
             f"{avail}; on CPU force more with ensure_host_devices() BEFORE "
             "first jax use")
-    return jax.make_mesh((mesh_nodes,), ("nodes",))
+    return jax.make_mesh((mesh_nodes,), ("nodes",),
+                         axis_types=(AxisType.Auto,))
 
 
 def ensure_host_devices(n: int) -> bool:
-    """Best-effort: force ``n`` host-platform XLA devices by appending
-    ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS`` — only
-    effective BEFORE the XLA backend initialises (jax import alone does
-    not initialise it; first device/array use does).  Returns True when
-    the flag is in place or the backend already exposes >= n devices,
-    False when the backend is already up with fewer (callers skip their
-    sharded path instead of erroring)."""
-    from jax._src import xla_bridge
-
-    if xla_bridge._backends:                      # backend already up
+    """Ask the CPU backend for ``n`` devices (``jax_num_cpu_devices``).
+    Only the CPU backend is affected: on a TPU host the device count is
+    the chips present, so callers still check ``len(jax.devices())``.
+    Only effective BEFORE the backends initialise (jax import alone does
+    not initialise them; first device/array use does).  Returns False
+    when they are already up with fewer than ``n`` devices."""
+    try:
+        jax.config.update("jax_num_cpu_devices", n)
+    except RuntimeError:                          # backends already up
         return len(jax.devices()) >= n
-    flags = os.environ.get("XLA_FLAGS", "")
-    m = re.search(r"--xla_force_host_platform_device_count=(\d+)", flags)
-    if m and int(m.group(1)) >= n:
-        return True
-    if m:                                         # raise an existing, smaller count
-        flags = flags.replace(m.group(0), "")
-    os.environ["XLA_FLAGS"] = (
-        flags + f" --xla_force_host_platform_device_count={n}").strip()
     return True
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing else is set here.  Otherwise the cache lives at
+    the fixed path ``<repo>/.cache/jax_compile``: the path is part of the
+    cache key, so it must not move between runs."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".cache", "jax_compile")
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache every program: the serving buckets compile in seconds each,
+    # under the defaults' one-second floor for some of them
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path

@@ -14,6 +14,7 @@ plus true queue-delay and per-stage wall-time percentiles.
 from __future__ import annotations
 
 import argparse
+from typing import Optional
 
 import numpy as np
 
@@ -28,6 +29,7 @@ from repro.faults import FaultInjector, FaultSchedule, attach_journals
 from repro.faults.schedule import PRESETS as FAULT_PRESETS
 from repro.core.storage_classifier import StorageClassifier
 from repro.data.synthetic import make_corpus, render_caption
+from repro.kernels import resolve_interpret
 from repro.runtime.serving import ServingEngine
 
 
@@ -36,7 +38,8 @@ def build_system(*, n_nodes: int = 4, corpus_n: int = 600,
                  eviction="LCU", use_scheduler=True,
                  use_prompt_optimizer=True, backend=None, seed=0,
                  node_speeds=None, routing: str = "score",
-                 latent_depths=None, mesh_nodes: int = 1):
+                 latent_depths=None, mesh_nodes: int = 1,
+                 use_pallas: Optional[bool] = None):
     """Assemble the full CacheGenius stack over the synthetic corpus.
 
     ``routing`` selects the Schedule stage's mode: ``"score"`` (default)
@@ -49,8 +52,18 @@ def build_system(*, n_nodes: int = 4, corpus_n: int = 600,
     "nodes" mesh; results stay bitwise identical to ``mesh_nodes=1``) —
     on CPU force the devices with
     :func:`repro.launch.mesh.ensure_host_devices` BEFORE first jax
-    use."""
-    images, captions, _ = make_corpus(corpus_n, res=32, seed=seed)
+    use.
+
+    The corpus is rendered at the backend's ``image_res`` (32 px for the
+    default :class:`NullBackend`), so cached references have the shape
+    the backend's img2img programs were compiled for.  ``use_pallas``
+    selects the Pallas retrieval scans for every node db and the
+    cluster index; ``None`` means exactly when the backend is a TPU,
+    where they compile (elsewhere the jnp scans run)."""
+    res = backend.image_res if backend is not None else 32
+    if use_pallas is None:
+        use_pallas = not resolve_interpret(None)
+    images, captions, _ = make_corpus(corpus_n, res=res, seed=seed)
     embedder = ProxyClipEmbedder(render_caption)
     img_vecs = embedder.embed_image(images)
     txt_vecs = embedder.embed_text(captions)
@@ -60,9 +73,10 @@ def build_system(*, n_nodes: int = 4, corpus_n: int = 600,
     payloads = np.array([blob.put(im) for im in images], np.int64)
     classifier = StorageClassifier(n_nodes)
     dbs = classifier.build_node_dbs(img_vecs, txt_vecs, payloads,
-                                    capacity_per_node=capacity_per_node)
+                                    capacity_per_node=capacity_per_node,
+                                    use_pallas=use_pallas)
     if backend is None:
-        backend = _null_backend(images)
+        backend = NullBackend(res)
     base_speeds = [1.0, 1.0, 0.82, 0.45]   # 4090D/4090D/3090/2070S
     speeds = node_speeds or [base_speeds[i % len(base_speeds)]
                              for i in range(n_nodes)]
@@ -96,6 +110,10 @@ class NullBackend(GenerationBackend):
         super().__init__()
         self.res = int(res)
 
+    @property
+    def image_res(self) -> int:
+        return self.res
+
     def txt2img_batch(self, prompts, steps, seeds):
         from repro.data.synthetic import render_caption as rc
         return np.stack([rc(p, res=self.res) for p in prompts])
@@ -116,10 +134,6 @@ class NullBackend(GenerationBackend):
         return self.img2img_batch(prompts, latents, steps_total - k, seeds)
 
 
-def _null_backend(corpus_images):
-    return NullBackend(res=corpus_images.shape[1])
-
-
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=300)
@@ -138,7 +152,8 @@ def main() -> int:
                     help="shard the cluster index's cache slabs over "
                     "this many devices (1-D 'nodes' mesh; scan results "
                     "stay bitwise identical to the single-device path); "
-                    "on CPU host devices are forced automatically")
+                    "on CPU host devices are forced automatically, and "
+                    "with fewer devices than this the command fails")
     ap.add_argument("--latent-cache", action="store_true",
                     help="archive noised img2img intermediates alongside "
                     "finished images and resume denoising from them "
@@ -209,14 +224,15 @@ def main() -> int:
         ap.error("--slot-capacity must be >= 1")
     if args.mesh_nodes < 1:
         ap.error("--mesh-nodes must be >= 1")
+    from repro.launch.mesh import enable_compile_cache, ensure_host_devices
     if args.mesh_nodes > 1:
-        # must happen before any jax device use below (backend init is
-        # lazy — an already-initialised smaller backend falls back)
-        from repro.launch.mesh import ensure_host_devices
-        if not ensure_host_devices(args.mesh_nodes):
-            print(f"# mesh-nodes={args.mesh_nodes} unavailable "
-                  "(backend already initialised); running unsharded")
-            args.mesh_nodes = 1
+        # before any jax device use: on CPU this asks for host devices
+        ensure_host_devices(args.mesh_nodes)
+    import jax
+    if len(jax.devices()) < args.mesh_nodes:
+        ap.error(f"--mesh-nodes {args.mesh_nodes} needs that many devices, "
+                 f"{jax.devices()[0].platform} has {len(jax.devices())}")
+    enable_compile_cache()
 
     if args.latent_depths is not None:
         latent_depths = tuple(int(d) for d in args.latent_depths.split(","))

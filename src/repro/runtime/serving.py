@@ -114,9 +114,12 @@ class DiffusionBackend(GenerationBackend):
                  *, schedule: Optional[DiffusionSchedule] = None,
                  latent_scale: float = 1.0,
                  img2img_strength: float = 0.6):
-        self.net_params = net_params
+        # one upload at construction: numpy parameters (as a pickled
+        # stack yields them) would otherwise cross to the device again
+        # on every launch
+        self.net_params = jax.device_put(net_params)
         self.net_cfg = net_cfg
-        self.vae_params = vae_params
+        self.vae_params = jax.device_put(vae_params)
         self.vae_cfg = vae_cfg
         self.embed_prompt = embed_prompt
         self.sched = schedule or DiffusionSchedule.linear(1000)
@@ -124,6 +127,12 @@ class DiffusionBackend(GenerationBackend):
         self.strength = img2img_strength
         self._compiled: Dict[Tuple[str, int, int], Any] = {}
         self.compile_seconds: Dict[Tuple[str, int, int], float] = {}
+
+    @property
+    def image_res(self) -> int:
+        """Side of the square images this backend generates and takes as
+        img2img references."""
+        return self.vae_cfg.downsample * self.net_cfg.img_res
 
     # -- jittable cores -----------------------------------------------------
     #
@@ -233,7 +242,7 @@ class DiffusionBackend(GenerationBackend):
         key = (kind, steps, batch)
         if key not in self._compiled:
             t0 = time.perf_counter()
-            res = self.vae_cfg.downsample * self.net_cfg.img_res
+            res = self.image_res
             lat_sds = jax.ShapeDtypeStruct(
                 (batch, self.net_cfg.img_res, self.net_cfg.img_res,
                  self.net_cfg.in_ch), jnp.float32)
@@ -369,8 +378,8 @@ class DiffusionBackend(GenerationBackend):
         batching numerics (identical noise trajectories by construction)."""
         n = len(prompts)
         if n == 0:
-            res = self.vae_cfg.downsample * self.net_cfg.img_res
-            return np.zeros((0, res, res, 3), np.float32)
+            return np.zeros((0, self.image_res, self.image_res, 3),
+                            np.float32)
         bucket = self._bucket(n)
         ctx, seeds_arr = self._pad_ctx_seeds(prompts, seeds, bucket)
         fn = self._get("txt2img", steps, bucket)
@@ -382,8 +391,8 @@ class DiffusionBackend(GenerationBackend):
         """Batched SDEdit img2img over stacked references (B, H, W, 3)."""
         n = len(prompts)
         if n == 0:
-            res = self.vae_cfg.downsample * self.net_cfg.img_res
-            return np.zeros((0, res, res, 3), np.float32)
+            return np.zeros((0, self.image_res, self.image_res, 3),
+                            np.float32)
         bucket = self._bucket(n)
         ctx, seeds_arr = self._pad_ctx_seeds(prompts, seeds, bucket)
         refs = np.asarray(references, np.float32)
@@ -406,8 +415,8 @@ class DiffusionBackend(GenerationBackend):
         the padding)."""
         n = len(prompts)
         if n == 0:
-            res = self.vae_cfg.downsample * self.net_cfg.img_res
-            return np.zeros((0, res, res, 3), np.float32)
+            return np.zeros((0, self.image_res, self.image_res, 3),
+                            np.float32)
         bucket = self._bucket(n)
         ctx, _ = self._pad_ctx_seeds(prompts, seeds, bucket)
         lats = np.asarray(latents, np.float32)

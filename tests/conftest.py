@@ -1,7 +1,7 @@
 """Shared fixtures: tiny synthetic corpus, proxy embedder, node VDB fleet.
 
-Multi-device harness: this conftest forces 8 XLA host-platform CPU
-devices (``--xla_force_host_platform_device_count=8``) at import — i.e.
+Multi-device harness: this conftest asks for 8 CPU devices
+(``jax_num_cpu_devices``, via ``ensure_host_devices``) at import — i.e.
 before any test can initialise the backend — so the mesh-sharded
 cluster-retrieval parity suite runs on any CI box.  The whole tier-1
 suite runs under the forced-8 world (single-device tests are

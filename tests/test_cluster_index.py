@@ -23,9 +23,10 @@ import jax.numpy as jnp
 
 from repro.core.cluster_index import ClusterIndex
 from repro.core.vdb import VectorDB, _union_topk
-from repro.kernels.ref import vdb_topk_sharded_ref
+from repro.kernels.ref import (vdb_topk_pernode_ref, vdb_topk_ref,
+                               vdb_topk_sharded_ref)
 from repro.kernels.vdb_topk import (NEG_INF, resolve_interpret, vdb_topk,
-                                    vdb_topk_sharded)
+                                    vdb_topk_pernode, vdb_topk_sharded)
 from repro.launch.serve import build_system
 
 
@@ -153,6 +154,42 @@ def test_sharded_kernel_matches_ref(qn, nodes, cap, k, block, mask_nodes):
     np.testing.assert_allclose(s_k[real], s_r[real], rtol=1e-5, atol=1e-6)
     # kernel sentinel: masked candidates sit at NEG_INF, never -inf
     assert np.isfinite(s_k).all()
+
+
+@pytest.mark.parametrize("mode", ["single", "masked", "global", "pernode"])
+@pytest.mark.parametrize("cap,block", [(200, 128), (300, 512), (130, 128)])
+def test_first_max_pick_breaks_ties_like_ref(cap, block, mode):
+    """Every slab row repeats one of five vectors, so scores tie in large
+    groups that straddle block boundaries; capacities are not multiples
+    of the 128-lane block.  The kernel's first-max pick must return the
+    same ids as ``jax.lax.top_k`` (lowest slot first among ties)."""
+    rng = np.random.default_rng(cap)
+    base = _unit(rng, 5, 16)
+    slabs = base[rng.integers(0, 5, size=(2, 3, cap))]
+    valid = rng.random((3, cap)) < 0.9
+    Q = np.concatenate([base[:3], _unit(rng, 1, 16)])
+    nids = np.array([0, 2, 1, 2], np.int32)
+    k = 12
+    args = [jnp.asarray(a) for a in (Q, slabs, valid, nids)]
+    if mode == "single":
+        got = vdb_topk(args[0], args[1][0, 0], args[2][0], k, block_n=block,
+                       interpret=True)
+        want = vdb_topk_ref(args[0], args[1][0, 0], args[2][0], k)
+    elif mode == "pernode":
+        got = vdb_topk_pernode(*args[:3], k, block_n=block, interpret=True)
+        want = vdb_topk_pernode_ref(*args[:3], k)
+    else:
+        masked = mode == "masked"
+        got = vdb_topk_sharded(*args, k, block_n=block, mask_nodes=masked,
+                               interpret=True)
+        want = vdb_topk_sharded_ref(*args, k, mask_nodes=masked)
+    s_k, i_k, s_r, i_r = map(np.asarray, (*got, *want))
+    real = np.isfinite(s_r) & (s_r > NEG_INF / 2)
+    assert real.sum() > 0
+    np.testing.assert_array_equal(np.where(real, i_k, -1),
+                                  np.where(real, i_r, -1))
+    # two programs (Pallas tile dot vs einsum): scores may differ by 1 ulp
+    np.testing.assert_allclose(s_k[real], s_r[real], rtol=1e-5, atol=1e-6)
 
 
 def test_interpret_default_is_backend_aware():

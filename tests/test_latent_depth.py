@@ -18,6 +18,7 @@ import warnings
 import numpy as np
 import pytest
 
+from repro.core.cluster_index import ClusterIndex
 from repro.core.latency_model import CostModel, LatencyModel
 from repro.core.lcu import LCUPolicy, LFUPolicy
 from repro.core.policy import GenerationPolicy, Route
@@ -134,9 +135,12 @@ def test_vdb_fresh_entry_access_count_is_one():
 
 def test_fused_scan_parity_with_depth_rows():
     """search_batch over a db holding mixed finished/latent rows must be
-    bit-identical to a standalone restore of the same snapshot — the depth
-    and source_id columns are host-side metadata the fused scan never
-    consumes."""
+    bit-identical to a restore of the same snapshot scanned by the same
+    program — the depth and source_id columns are host-side metadata the
+    fused scan never consumes.  The restored fleet gets its own
+    ClusterIndex of the served fleet's shape, so both sides run the
+    identical stacked scan (a different scan program may differ in the
+    last ulp)."""
     system, emb, _, _ = build_system(n_nodes=2, corpus_n=32,
                                      capacity_per_node=600, seed=0,
                                      latent_depths=True)
@@ -146,8 +150,10 @@ def test_fused_scan_parity_with_depth_rows():
     q = emb.embed_text(["a medium red circle at the center on a black "
                         "background", "a small blue square at the left on "
                         "a gray background"])
-    for db in system.dbs:
-        solo = VectorDB.restore(db.dim, db.capacity, db.snapshot())
+    restored = [VectorDB.restore(db.dim, db.capacity, db.snapshot())
+                for db in system.dbs]
+    ClusterIndex.from_dbs(restored, mesh_nodes=system.mesh_nodes)
+    for db, solo in zip(system.dbs, restored):
         got = db.search_batch(q, 4)
         want = solo.search_batch(q, 4)
         for g, w in zip(got, want):
