@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.vdb import VectorDB, _union_topk
+from repro.runtime.tracing import span
 from repro.utils import l2n, next_pow2
 
 
@@ -85,8 +86,9 @@ def _fused_topk(slabs, valid, queries, node_ids, k: int, mask_nodes: bool):
     ``node * cap + col``), numerically the per-node ``_masked_topk_batch``
     restricted to each query's scheduled node."""
     from repro.kernels.ref import vdb_topk_sharded_ref
-    return vdb_topk_sharded_ref(queries, slabs, valid, node_ids, k,
-                                mask_nodes=mask_nodes)
+    with jax.named_scope("vdb_scan"):
+        return vdb_topk_sharded_ref(queries, slabs, valid, node_ids, k,
+                                    mask_nodes=mask_nodes)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -94,7 +96,8 @@ def _fused_topk_pernode(slabs, valid, queries, k: int):
     """jnp path of the per-node scan (one einsum + per-node top-k) —
     jitted delegation to the shared test oracle."""
     from repro.kernels.ref import vdb_topk_pernode_ref
-    return vdb_topk_pernode_ref(queries, slabs, valid, k)
+    with jax.named_scope("vdb_scan"):
+        return vdb_topk_pernode_ref(queries, slabs, valid, k)
 
 
 class ClusterIndex:
@@ -427,21 +430,26 @@ class ClusterIndex:
         and the Retrieve stage can reuse the chosen node's row without a
         second scan while the scheduler routes on every node's best
         match.
+
+        The launch and the host merge are one ``scan`` span: ``queries``
+        is the true batch, ``rows`` the slots the launch reads (every
+        slot of every node, valid or not).
         """
         Qn, b = self._prep_queries(query_vecs)
         if b == 0:
             return []
         k = min(k, self.capacity)
-        s, i = self._scan(Qn, None, k, index, mask_nodes=False,
-                          per_node=True)         # (planes, nodes, Qpad, k)
-        out: List[List[Tuple[np.ndarray, np.ndarray]]] = []
-        for row in range(b):
-            per_node = []
-            for node in range(self.n_nodes):
-                local = i[:, node, row] - node * self.capacity
-                per_node.append(_union_topk(list(s[:, node, row]),
-                                            list(local)))
-            out.append(per_node)
+        with span("scan", queries=b, rows=self.n_nodes * self.capacity):
+            s, i = self._scan(Qn, None, k, index, mask_nodes=False,
+                              per_node=True)     # (planes, nodes, Qpad, k)
+            out: List[List[Tuple[np.ndarray, np.ndarray]]] = []
+            for row in range(b):
+                per_node = []
+                for node in range(self.n_nodes):
+                    local = i[:, node, row] - node * self.capacity
+                    per_node.append(_union_topk(list(s[:, node, row]),
+                                                list(local)))
+                out.append(per_node)
         return out
 
     # -- derived state ------------------------------------------------------

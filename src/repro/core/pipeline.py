@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.policy import Route
 from repro.core.scheduler import ScheduleDecision
+from repro.runtime.tracing import span
 from repro.utils import l2n, stable_hash
 
 
@@ -275,7 +276,6 @@ class BatchContext:
 
     system: object             # CacheGenius
     states: List[RequestState]
-    t_wall0: float
     pvecs: Optional[np.ndarray] = None   # (B, 512) stacked text embeddings
     # step-level admission: (qvec, handle) of every earlier gen-plan
     # request that is still in flight or awaiting finalize — requests a
@@ -872,27 +872,17 @@ class FinishStage:
     inside one batch cannot see a mid-batch sweep that already happened
     sequentially.
 
-    Wall-clock accounting: each request reports the micro-batch's total
-    wall time divided by the batch size (batch-amortised per-request
-    cost); the batch total itself is appended to
-    ``ServeStats.batch_wall_latencies``.  The total is taken AFTER the
-    result loop AND its interleaved maintenance sweeps, so sweeps stay
-    inside the measurement; results and stats are back-filled with the
-    final share.
-
-    The TRUE per-request accounting (``stage_walls`` / ``wall_total`` /
-    ``queue_delay``) is back-filled by the ``ServePipeline.run`` driver
-    from the per-stage timestamps once the last stage returns — the
-    amortised ``wall_latency`` stays only as the legacy throughput share.
+    Per-request wall time (``stage_walls`` / ``wall_total`` /
+    ``queue_delay``) is back-filled by ``ServePipeline`` from the
+    per-stage timestamps once the last stage returns; each sweep is a
+    ``maintain`` span inside this stage's ``stage.Finish`` span.
     """
 
     name = "Finish"
 
     def run(self, ctx: BatchContext) -> None:
         system = ctx.system
-        n = len(ctx.states)
         interval = system.maintenance_interval
-        wall = 0.0          # back-filled once the batch total is known
         for s in ctx.states:
             if s.archive_deferred:
                 _do_archive(system, s)
@@ -901,25 +891,25 @@ class FinishStage:
             if p.kind == "alias":
                 s.image = ctx.states[p.target].image
                 s.result = system._finish(
-                    s.image, Route.HIT_RETURN, -1, 1.0, wall,
+                    s.image, Route.HIT_RETURN, -1, 1.0,
                     steps=0, retrieved=False, fast="history")
             elif p.kind == "history":
                 s.image = p.image
                 s.result = system._finish(
-                    s.image, Route.HIT_RETURN, -1, 1.0, wall,
+                    s.image, Route.HIT_RETURN, -1, 1.0,
                     steps=0, retrieved=False, fast="history")
             elif p.kind == "gen" and p.fast == "priority":
                 s.result = system._finish(
-                    s.image, Route.TXT2IMG, p.node, 0.0, wall,
+                    s.image, Route.TXT2IMG, p.node, 0.0,
                     steps=p.steps, retrieved=False, fast="priority")
             elif p.kind == "cached":
                 s.image = p.image
                 s.result = system._finish(
-                    s.image, Route.HIT_RETURN, p.node, p.score, wall,
+                    s.image, Route.HIT_RETURN, p.node, p.score,
                     steps=0)
             else:
                 s.result = system._finish(
-                    s.image, p.route, p.node, p.score, wall,
+                    s.image, p.route, p.node, p.score,
                     steps=p.steps,
                     resumed_from=(p.resume_k if p.latent is not None
                                   else -1),
@@ -927,12 +917,6 @@ class FinishStage:
             # exact crossing: sweep the moment the counter hits a multiple
             if system.stats.requests % interval == 0:
                 system.maintain()
-        t_batch = time.perf_counter() - ctx.t_wall0
-        wall = t_batch / n
-        system.stats.batch_wall_latencies.append(t_batch)
-        system.stats.wall_latencies[-n:] = [wall] * n
-        for s in ctx.states:
-            s.result.wall_latency = wall
 
 
 # ---------------------------------------------------------------------------
@@ -952,9 +936,11 @@ class ServePipeline:
     every stage in order, and returns the states with ``result`` set.
 
     Timing contract: every state records ``admitted_at`` (pipeline entry)
-    and ``stage_ts[name]`` (stage end) on the ``time.perf_counter`` clock,
-    so per-stage wall times are real measurements, not the batch-amortised
-    share.  After the last stage the driver back-fills each result's
+    and ``stage_ts[name]`` (stage end) on the ``time.perf_counter`` clock.
+    Each stage runs inside a ``stage.<Name>`` program span
+    (:mod:`repro.runtime.tracing`) whose end is the instant stamped, so
+    the per-request trail and the profiler trace cannot drift apart.
+    After the last stage the pipeline back-fills each result's
     ``stage_walls`` (per-stage durations), ``wall_total`` (admission to
     Finish), and — when the caller supplied ``submitted_ats`` on the same
     clock — ``queue_delay`` (submission to admission).  Stages run at
@@ -976,9 +962,22 @@ class ServePipeline:
             quality_tiers: Optional[Sequence[bool]] = None,
             submitted_ats: Optional[Sequence[float]] = None,
             ) -> List[RequestState]:
-        n = len(prompts)
-        if n == 0:
+        states = self._admit(system, prompts, seeds, quality_tiers,
+                             submitted_ats)
+        if not states:
             return []
+        self._run_stages(BatchContext(system=system, states=states),
+                         self.stages)
+        for s in states:
+            self._backfill(s)
+        return states
+
+    @staticmethod
+    def _admit(system, prompts, seeds, quality_tiers, submitted_ats,
+               ) -> List[RequestState]:
+        """One :class:`RequestState` per prompt, admitted now: the system
+        clock ticks once per request."""
+        n = len(prompts)
         t0 = time.perf_counter()
         seeds = list(seeds) if seeds is not None else [0] * n
         tiers = (list(quality_tiers) if quality_tiers is not None
@@ -991,27 +990,35 @@ class ServePipeline:
                                submitted_at=subs[i], admitted_at=t0)
                   for i, p in enumerate(prompts)]
         system.clock += n
-        ctx = BatchContext(system=system, states=states, t_wall0=t0)
-        for stage in self.stages:
-            stage.run(ctx)
-            ts = time.perf_counter()
-            for s in states:
-                s.stage_ts[stage.name] = ts
-        # back-fill per-request timing onto the finished results
-        last = self.stages[-1].name
-        for s in states:
-            if s.result is None:       # custom stage list without a Finish
-                continue
-            prev = t0
-            walls: Dict[str, float] = {}
-            for name in self.stage_names:
-                walls[name] = s.stage_ts[name] - prev
-                prev = s.stage_ts[name]
-            s.result.stage_walls = walls
-            s.result.wall_total = s.stage_ts[last] - s.admitted_at
-            if s.submitted_at is not None:
-                s.result.queue_delay = s.admitted_at - s.submitted_at
         return states
+
+    @staticmethod
+    def _run_stages(ctx: BatchContext, stages: Sequence) -> None:
+        """Run ``stages`` in order over the batch, each inside its
+        ``stage.<Name>`` span, stamping every state's ``stage_ts[name]``
+        as the span's last act."""
+        n = len(ctx.states)
+        for stage in stages:
+            with span("stage." + stage.name, n=n):
+                stage.run(ctx)
+                ts = time.perf_counter()
+            for s in ctx.states:
+                s.stage_ts[stage.name] = ts
+
+    def _backfill(self, state: RequestState) -> None:
+        """Per-request timing onto a finished result, from the state's
+        own trail: ``sum(stage_walls) == wall_total``."""
+        if state.result is None:     # custom stage list without a Finish
+            return
+        prev = state.admitted_at
+        walls: Dict[str, float] = {}
+        for name in self.stage_names:
+            walls[name] = state.stage_ts[name] - prev
+            prev = state.stage_ts[name]
+        state.result.stage_walls = walls
+        state.result.wall_total = prev - state.admitted_at
+        if state.submitted_at is not None:
+            state.result.queue_delay = state.admitted_at - state.submitted_at
 
     # -- step-level split: admit now, generate over many boundaries, -----------
     #    finalize per slot in submission order
@@ -1041,29 +1048,14 @@ class ServePipeline:
         Archive/Finish land per slot via :meth:`finalize`.  ``inflight``
         seeds the Plan stage's coalescing set with earlier unfinalized gen
         requests (see :class:`BatchContext`)."""
-        n = len(prompts)
-        if n == 0:
+        if len(prompts) == 0:
             return []
         gen_i = self._stage_index("Generate")
-        t0 = time.perf_counter()
-        seeds = list(seeds) if seeds is not None else [0] * n
-        tiers = (list(quality_tiers) if quality_tiers is not None
-                 else [False] * n)
-        subs = (list(submitted_ats) if submitted_ats is not None
-                else [None] * n)
-        states = [RequestState(index=i, raw_prompt=str(p), prompt=str(p),
-                               seed=seeds[i], quality_tier=tiers[i],
-                               clock=system.clock + i + 1,
-                               submitted_at=subs[i], admitted_at=t0)
-                  for i, p in enumerate(prompts)]
-        system.clock += n
-        ctx = BatchContext(system=system, states=states, t_wall0=t0,
-                           inflight=inflight)
-        for stage in self.stages[:gen_i]:
-            stage.run(ctx)
-            ts = time.perf_counter()
-            for s in states:
-                s.stage_ts[stage.name] = ts
+        states = self._admit(system, prompts, seeds, quality_tiers,
+                             submitted_ats)
+        self._run_stages(BatchContext(system=system, states=states,
+                                      inflight=inflight),
+                         self.stages[:gen_i])
         return states
 
     def finalize(self, system, state: RequestState) -> RequestState:
@@ -1089,20 +1081,7 @@ class ServePipeline:
         t0 = time.perf_counter()
         for name in self.stage_names[:arch_i]:
             state.stage_ts.setdefault(name, t0)
-        ctx = BatchContext(system=system, states=[state], t_wall0=t0)
-        for stage in self.stages[arch_i:]:
-            stage.run(ctx)
-            ts = time.perf_counter()
-            state.stage_ts[stage.name] = ts
-        if state.result is not None:
-            prev = state.admitted_at
-            walls: Dict[str, float] = {}
-            for name in self.stage_names:
-                walls[name] = state.stage_ts[name] - prev
-                prev = state.stage_ts[name]
-            state.result.stage_walls = walls
-            state.result.wall_total = (state.stage_ts[self.stages[-1].name]
-                                       - state.admitted_at)
-            if state.submitted_at is not None:
-                state.result.queue_delay = state.admitted_at - state.submitted_at
+        self._run_stages(BatchContext(system=system, states=[state]),
+                         self.stages[arch_i:])
+        self._backfill(state)
         return state

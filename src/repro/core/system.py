@@ -79,6 +79,7 @@ from repro.core.prompt_optimizer import PromptOptimizer
 from repro.core.scheduler import NodeInfo, RequestScheduler
 from repro.core.storage_classifier import StorageClassifier
 from repro.core.vdb import BlobStore, VectorDB
+from repro.runtime.tracing import span
 
 __all__ = ["CacheGenius", "CallableBackend", "GenerationBackend", "Plan",
            "RequestState", "Route", "ServePipeline", "ServeResult",
@@ -92,7 +93,6 @@ class ServeResult:
     node: int
     score: float
     latency: float            # Eq. 8 modelled latency
-    wall_latency: float       # batch-amortised measured wall-clock on this host
     steps: int
     fast_path: Optional[str] = None
     # latent-depth cache: depth the denoising chain resumed from (-1 =
@@ -113,11 +113,6 @@ class ServeResult:
 class ServeStats:
     route_counts: Dict[str, int] = field(default_factory=dict)
     latencies: List[float] = field(default_factory=list)
-    wall_latencies: List[float] = field(default_factory=list)
-    # one entry per served micro-batch: that batch's TOTAL wall-clock.
-    # Per-request ``wall_latencies`` are batch-amortised (total / batch
-    # size), so sum(wall_latencies) ~= sum(batch_wall_latencies).
-    batch_wall_latencies: List[float] = field(default_factory=list)
     scores: List[float] = field(default_factory=list)
     requests: int = 0
     cache_hits: int = 0        # HIT_RETURN + history fast path
@@ -135,7 +130,6 @@ class ServeStats:
         key = r.fast_path or r.route.value
         self.route_counts[key] = self.route_counts.get(key, 0) + 1
         self.latencies.append(r.latency)
-        self.wall_latencies.append(r.wall_latency)
         self.scores.append(r.score)
         self.total_steps += r.steps
         if r.resumed_from >= 0:
@@ -329,7 +323,7 @@ class CacheGenius:
             self.dbs[node].add(ivec[None], pvec[None], np.array([pid]), t)
         self.scheduler.record_result(pvec, pid)
 
-    def _finish(self, img, route, node, score, wall, *, steps, retrieved=True,
+    def _finish(self, img, route, node, score, *, steps, retrieved=True,
                 fast=None, resumed_from=-1, degraded=False) -> ServeResult:
         speed = (self.scheduler.nodes[node].speed if 0 <= node < len(self.dbs)
                  else max(n.speed for n in self.scheduler.nodes))
@@ -341,22 +335,26 @@ class CacheGenius:
         self.cost_model.charge(max(node, 0), gpu_s,
                                vdb_seconds=self.latency_model.t_retrieve if retrieved else 0.0)
         res = ServeResult(image=img, route=route, node=node, score=score,
-                          latency=lat, wall_latency=wall,
-                          steps=steps, fast_path=fast,
+                          latency=lat, steps=steps, fast_path=fast,
                           resumed_from=resumed_from, degraded=degraded)
         self.stats.record(res)
         return res
 
     def maintain(self) -> Dict[int, np.ndarray]:
-        """Run the eviction policy across all node VDBs (Algorithm 2)."""
-        evicted = self.eviction.maintain(self.dbs, self.cache_capacity)
-        all_payloads = []
-        for _, payloads in evicted.items():
-            for p in payloads:
-                self.blob_store.delete(int(p))
-                all_payloads.append(int(p))
-        # keep the historical-query cache consistent with the blob store
-        self.scheduler.invalidate_payloads(all_payloads)
+        """Run the eviction policy across all node VDBs (Algorithm 2), as
+        one ``maintain`` span: ``rows`` is the valid slots the sweep
+        visits, ``evicted`` the slots it frees."""
+        with span("maintain", rows=self.total_size) as sp:
+            evicted = self.eviction.maintain(self.dbs, self.cache_capacity)
+            all_payloads = []
+            for _, payloads in evicted.items():
+                for p in payloads:
+                    self.blob_store.delete(int(p))
+                    all_payloads.append(int(p))
+            # keep the historical-query cache consistent with the blob
+            # store
+            self.scheduler.invalidate_payloads(all_payloads)
+            sp.set_metadata(evicted=len(all_payloads))
         return evicted
 
     def fail_node(self, node: int) -> None:

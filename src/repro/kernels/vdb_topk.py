@@ -354,7 +354,8 @@ def _mesh_scan_fn(mesh, n_shard: int, capacity: int, k: int,
     (mesh, shape, mode) across ClusterIndex rebuilds/restacks."""
     from jax.sharding import PartitionSpec as P
 
-    def local(slabs_l, valid_l, queries, node_ids):
+    # the name is the program's: it reads jit_vdb_topk_mesh_shard
+    def vdb_topk_mesh_shard(slabs_l, valid_l, queries, node_ids):
         shard = jax.lax.axis_index("nodes")
         offset = shard * n_shard * capacity
         if per_node:
@@ -383,7 +384,7 @@ def _mesh_scan_fn(mesh, n_shard: int, capacity: int, k: int,
     out_specs = ((P(None, "nodes", None, None),) * 2 if per_node
                  else (P("nodes", None, None, None),) * 2)
     fn = jax.shard_map(
-        local, mesh=mesh,
+        vdb_topk_mesh_shard, mesh=mesh,
         in_specs=(P(None, "nodes", None, None), P("nodes", None),
                   P(None, None), P(None)),
         out_specs=out_specs, check_vma=False)

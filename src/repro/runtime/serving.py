@@ -83,6 +83,7 @@ from repro.models.diffusion.sampler import (ddim_sample, ddim_timesteps,
                                             resume_sample, sdedit_start,
                                             step_slots)
 from repro.models.diffusion.schedule import DiffusionSchedule
+from repro.runtime.tracing import span
 from repro.utils import next_pow2
 
 
@@ -239,72 +240,76 @@ class DiffusionBackend(GenerationBackend):
     # -- AOT bucket management -----------------------------------------------
 
     def _get(self, kind: str, steps: int, batch: int):
+        """The compiled program of one (kind, steps, batch) bucket,
+        compiled on first use inside a ``compile`` span.  Each program's
+        function carries the kind's name, so its XLA module reads
+        ``jit_<name>`` in a device trace (``jit_step_slots``,
+        ``jit_slot_decode``, ``jit_resume``, ...)."""
         key = (kind, steps, batch)
         if key not in self._compiled:
-            t0 = time.perf_counter()
-            res = self.image_res
-            lat_sds = jax.ShapeDtypeStruct(
-                (batch, self.net_cfg.img_res, self.net_cfg.img_res,
-                 self.net_cfg.in_ch), jnp.float32)
-            if kind == "txt2img":
-                fn = jax.jit(lambda n, v, c, s: self._txt2img_core(
-                    n, v, c, s, steps, batch))
-                args = (self.net_params, self.vae_params,
-                        jax.ShapeDtypeStruct((batch, self.net_cfg.ctx_dim),
-                                             jnp.float32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32))
-            elif kind.startswith("resume@"):
-                k = int(kind.split("@", 1)[1])
-                fn = jax.jit(lambda n, v, l, c: self._resume_core(
-                    n, v, l, c, steps, k))
-                args = (self.net_params, self.vae_params, lat_sds,
-                        jax.ShapeDtypeStruct((batch, self.net_cfg.ctx_dim),
-                                             jnp.float32))
-            elif kind.startswith("latents@"):
-                depths = tuple(int(d) for d in
-                               kind.split("@", 1)[1].split(","))
-                fn = jax.jit(lambda v, i, s: self._archive_latents_core(
-                    v, i, s, depths, steps))
-                args = (self.vae_params,
-                        jax.ShapeDtypeStruct((batch, res, res, 3),
-                                             jnp.float32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32))
-            elif kind == "step_slots":
-                # steps is 0 for slot kinds: ONE compiled program per slot
-                # capacity covers every mixture of per-slot step counts
-                fn = jax.jit(lambda n, x, c, t, tp, a: self._step_slots_core(
-                    n, x, c, t, tp, a))
-                args = (self.net_params, lat_sds,
-                        jax.ShapeDtypeStruct((batch, self.net_cfg.ctx_dim),
-                                             jnp.float32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32),
-                        jax.ShapeDtypeStruct((batch,), jnp.bool_))
-            elif kind == "slot_noise":
-                fn = jax.jit(self._slot_noise_core)
-                args = (jax.ShapeDtypeStruct((batch,), jnp.int32),)
-            elif kind == "slot_img_init":
-                fn = jax.jit(lambda v, r, s: self._slot_img_init_core(
-                    v, r, s))
-                args = (self.vae_params,
-                        jax.ShapeDtypeStruct((batch, res, res, 3),
-                                             jnp.float32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32))
-            elif kind == "slot_decode":
-                fn = jax.jit(lambda v, z: self._slot_decode_core(v, z))
-                args = (self.vae_params, lat_sds)
-            else:
-                fn = jax.jit(lambda n, v, r, c, s: self._img2img_core(
-                    n, v, r, c, s, steps))
-                args = (self.net_params, self.vae_params,
-                        jax.ShapeDtypeStruct((batch, res, res, 3), jnp.float32),
-                        jax.ShapeDtypeStruct((batch, self.net_cfg.ctx_dim),
-                                             jnp.float32),
-                        jax.ShapeDtypeStruct((batch,), jnp.int32))
-            self._compiled[key] = fn.lower(
-                *jax.tree_util.tree_map(_to_sds, args)).compile()
-            self.compile_seconds[key] = time.perf_counter() - t0
+            with span("compile", kind=kind, batch=batch):
+                t0 = time.perf_counter()
+                fn, args = self._program(kind, steps, batch)
+                self._compiled[key] = jax.jit(fn).lower(
+                    *jax.tree_util.tree_map(_to_sds, args)).compile()
+                self.compile_seconds[key] = time.perf_counter() - t0
         return self._compiled[key]
+
+    def _program(self, kind: str, steps: int, batch: int):
+        """``(fn, example args)`` of one bucket: ``fn`` is a named
+        function over the bucket's arguments, with ``steps``, ``batch``
+        and the kind's parameters closed over."""
+        res = self.image_res
+        cfg = self.net_cfg
+        lat_sds = jax.ShapeDtypeStruct(
+            (batch, cfg.img_res, cfg.img_res, cfg.in_ch), jnp.float32)
+        ctx_sds = jax.ShapeDtypeStruct((batch, cfg.ctx_dim), jnp.float32)
+        img_sds = jax.ShapeDtypeStruct((batch, res, res, 3), jnp.float32)
+        seeds_sds = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        if kind == "txt2img":
+            def txt2img(n, v, c, s):
+                return self._txt2img_core(n, v, c, s, steps, batch)
+            return txt2img, (self.net_params, self.vae_params, ctx_sds,
+                             seeds_sds)
+        if kind.startswith("resume@"):
+            k = int(kind.split("@", 1)[1])
+
+            def resume(n, v, l, c):
+                return self._resume_core(n, v, l, c, steps, k)
+            return resume, (self.net_params, self.vae_params, lat_sds,
+                            ctx_sds)
+        if kind.startswith("latents@"):
+            depths = tuple(int(d) for d in kind.split("@", 1)[1].split(","))
+
+            def latents(v, i, s):
+                return self._archive_latents_core(v, i, s, depths, steps)
+            return latents, (self.vae_params, img_sds, seeds_sds)
+        if kind == "step_slots":
+            # steps is 0 for slot kinds: ONE compiled program per slot
+            # capacity covers every mixture of per-slot step counts
+            def step_slots(n, x, c, t, tp, a):
+                return self._step_slots_core(n, x, c, t, tp, a)
+            steps_sds = jax.ShapeDtypeStruct((batch,), jnp.int32)
+            return step_slots, (self.net_params, lat_sds, ctx_sds,
+                                steps_sds, steps_sds,
+                                jax.ShapeDtypeStruct((batch,), jnp.bool_))
+        if kind == "slot_noise":
+            def slot_noise(s):
+                return self._slot_noise_core(s)
+            return slot_noise, (seeds_sds,)
+        if kind == "slot_img_init":
+            def slot_img_init(v, r, s):
+                return self._slot_img_init_core(v, r, s)
+            return slot_img_init, (self.vae_params, img_sds, seeds_sds)
+        if kind == "slot_decode":
+            def slot_decode(v, z):
+                return self._slot_decode_core(v, z)
+            return slot_decode, (self.vae_params, lat_sds)
+
+        def img2img(n, v, r, c, s):
+            return self._img2img_core(n, v, r, c, s, steps)
+        return img2img, (self.net_params, self.vae_params, img_sds, ctx_sds,
+                         seeds_sds)
 
     def precompile(self, *, step_buckets: Sequence[int] = (20, 30),
                    batch_buckets: Sequence[int] = (1,),
@@ -514,36 +519,41 @@ class DiffusionSlotEngine:
     def admit(self, state, handle: int) -> None:
         """Seat one planned ``gen`` request in a free slot: compute its
         initial latent (per-request seed-noise semantics preserved) and
-        its DDIM timestep sub-sequence."""
-        b = self.backend
+        its DDIM timestep sub-sequence.  One ``slot.seat`` span, ``kind``
+        naming how the latent starts: ``noise`` (txt2img), ``img_init``
+        (img2img) or ``resume`` (an archived latent)."""
         plan = state.plan
         slot = int(np.argmin(self._active))
         if self._active[slot]:
             raise RuntimeError("slot engine is full")
+        kind = ("resume" if plan.latent is not None
+                else "img_init" if plan.ref is not None else "noise")
+        b = self.backend
         seeds = jnp.asarray([state.seed], jnp.int32)
-        if plan.latent is not None:
-            # resume@k: the last steps of the steps_total-step truncated
-            # img2img chain (same geometry as resume_sample)
-            steps_total = int(plan.steps) + int(plan.resume_k)
-            ts = ddim_timesteps(b.sched.T, steps_total,
-                                t_start=int(b.strength * b.sched.T))
-            ts = np.asarray(ts[int(plan.resume_k):])
-            x0 = np.asarray(plan.latent, np.float32)
-        elif plan.ref is not None:
-            ts = np.asarray(ddim_timesteps(
-                b.sched.T, int(plan.steps),
-                t_start=int(b.strength * b.sched.T)))
-            fn = b._get("slot_img_init", 0, 1)
-            x0 = np.asarray(fn(b.vae_params,
-                               jnp.asarray(plan.ref, jnp.float32)[None],
-                               seeds)[0])
-        else:
-            ts = np.asarray(ddim_timesteps(b.sched.T, int(plan.steps)))
-            fn = b._get("slot_noise", 0, 1)
-            x0 = np.asarray(fn(seeds)[0])
-        self._lat[slot] = x0
-        self._ctx[slot] = np.asarray(b.embed_prompt(state.prompt),
-                                     np.float32)
+        with span("slot.seat", req=int(handle), kind=kind):
+            if plan.latent is not None:
+                # resume@k: the last steps of the steps_total-step truncated
+                # img2img chain (same geometry as resume_sample)
+                steps_total = int(plan.steps) + int(plan.resume_k)
+                ts = ddim_timesteps(b.sched.T, steps_total,
+                                    t_start=int(b.strength * b.sched.T))
+                ts = np.asarray(ts[int(plan.resume_k):])
+                x0 = np.asarray(plan.latent, np.float32)
+            elif plan.ref is not None:
+                ts = np.asarray(ddim_timesteps(
+                    b.sched.T, int(plan.steps),
+                    t_start=int(b.strength * b.sched.T)))
+                fn = b._get("slot_img_init", 0, 1)
+                x0 = np.asarray(fn(b.vae_params,
+                                   jnp.asarray(plan.ref, jnp.float32)[None],
+                                   seeds)[0])
+            else:
+                ts = np.asarray(ddim_timesteps(b.sched.T, int(plan.steps)))
+                fn = b._get("slot_noise", 0, 1)
+                x0 = np.asarray(fn(seeds)[0])
+            self._lat[slot] = x0
+            self._ctx[slot] = np.asarray(b.embed_prompt(state.prompt),
+                                         np.float32)
         self._ts[slot] = ts
         self._pos[slot] = 0
         self._state[slot] = state
@@ -554,39 +564,51 @@ class DiffusionSlotEngine:
     def step(self) -> List[Tuple[int, object]]:
         """Advance every active slot one DDIM step (one compiled launch);
         decode and free slots whose chain just finished.  Returns the
-        retired ``(handle, state)`` pairs (``state.image`` set)."""
+        retired ``(handle, state)`` pairs (``state.image`` set).
+
+        One ``slot.step`` span (``active`` slots) whose children split
+        the host round trip: ``slot.upload`` (the slot buffer and
+        timesteps to the device), ``slot.launch`` (the program, to its
+        end), ``slot.download`` (the buffer back) and one ``slot.decode``
+        per retiring slot (``req``)."""
         b = self.backend
-        t = np.zeros((self.capacity,), np.int32)
-        tp = np.full((self.capacity,), -1, np.int32)
-        for i in range(self.capacity):
-            if not self._active[i]:
-                continue
-            ts, p = self._ts[i], self._pos[i]
-            t[i] = ts[p]
-            tp[i] = ts[p + 1] if p + 1 < len(ts) else -1
-        fn = b._get("step_slots", 0, self.capacity)
-        out = fn(b.net_params, jnp.asarray(self._lat),
-                 jnp.asarray(self._ctx), jnp.asarray(t), jnp.asarray(tp),
-                 jnp.asarray(self._active))
-        self._lat = np.array(out)   # copy: the slot buffer stays writable
-        self.step_calls += 1
-        retired: List[Tuple[int, object]] = []
-        dec = b._get("slot_decode", 0, 1)
-        for i in range(self.capacity):
-            if not self._active[i]:
-                continue
-            self._pos[i] += 1
-            self.progress[self._handle[i]].append(self._pos[i])
-            if self._pos[i] >= len(self._ts[i]):
-                img = np.asarray(dec(b.vae_params,
-                                     jnp.asarray(self._lat[i])[None])[0])
-                st = self._state[i]
-                st.image = img
-                retired.append((self._handle[i], st))
-                self._active[i] = False
-                self._ts[i] = None
-                self._state[i] = None
-                self._handle[i] = -1
+        with span("slot.step", active=self.active_count()):
+            t = np.zeros((self.capacity,), np.int32)
+            tp = np.full((self.capacity,), -1, np.int32)
+            for i in range(self.capacity):
+                if not self._active[i]:
+                    continue
+                ts, p = self._ts[i], self._pos[i]
+                t[i] = ts[p]
+                tp[i] = ts[p + 1] if p + 1 < len(ts) else -1
+            fn = b._get("step_slots", 0, self.capacity)
+            with span("slot.upload"):
+                args = (jnp.asarray(self._lat), jnp.asarray(self._ctx),
+                        jnp.asarray(t), jnp.asarray(tp),
+                        jnp.asarray(self._active))
+            with span("slot.launch"):
+                out = jax.block_until_ready(fn(b.net_params, *args))
+            with span("slot.download"):
+                self._lat = np.array(out)   # copy: the buffer stays writable
+            self.step_calls += 1
+            retired: List[Tuple[int, object]] = []
+            dec = b._get("slot_decode", 0, 1)
+            for i in range(self.capacity):
+                if not self._active[i]:
+                    continue
+                self._pos[i] += 1
+                self.progress[self._handle[i]].append(self._pos[i])
+                if self._pos[i] >= len(self._ts[i]):
+                    with span("slot.decode", req=self._handle[i]):
+                        z = jnp.asarray(self._lat[i])[None]
+                        img = np.asarray(dec(b.vae_params, z)[0])
+                    st = self._state[i]
+                    st.image = img
+                    retired.append((self._handle[i], st))
+                    self._active[i] = False
+                    self._ts[i] = None
+                    self._state[i] = None
+                    self._handle[i] = -1
         return retired
 
 
@@ -682,6 +704,11 @@ class Completed:
     result: ServeResult
     queue_delay: float          # seconds actually waited before admission
     finished_at: float = 0.0    # engine-clock instant the result came back
+    # step-level only: engine-clock seconds from the instant the result
+    # was ready to the start of its finalize, i.e. the time it was held
+    # by submission-order release alone (0 in group mode, which has no
+    # such gate)
+    release_wait: float = 0.0
 
 
 class ServingEngine:
@@ -831,33 +858,34 @@ class ServingEngine:
             while pending and pending[0].arrival_time <= now + 1e-12:
                 ready.append(pending.popleft())
 
-        while pending or ready:
-            admit_arrived()
-            if mode == "drain":
-                while len(ready) < self.max_batch and pending:
+        with span("serve.run", requests=len(pending)):
+            while pending or ready:
+                admit_arrived()
+                if mode == "drain":
+                    while len(ready) < self.max_batch and pending:
+                        now = max(now, pending[0].arrival_time)
+                        admit_arrived()
+                if not ready:
                     now = max(now, pending[0].arrival_time)
-                    admit_arrived()
-            if not ready:
-                now = max(now, pending[0].arrival_time)
-                continue
-            batch, ready = ready[: self.max_batch], ready[self.max_batch:]
-            if on_step is not None:
-                on_step(group_no)
-            group_no += 1
-            admitted = now
-            t0 = time.perf_counter()
-            results = self.system.serve_batch(
-                [r.prompt for r in batch],
-                seeds=[r.seed for r in batch],
-                quality_tiers=[r.quality_tier for r in batch])
-            now = admitted + (time.perf_counter() - t0)
-            for r, res in zip(batch, results):
-                res.queue_delay = admitted - r.arrival_time
-                req = Request(r.prompt, r.seed, r.quality_tier,
-                              submitted_at=r.arrival_time,
-                              tenant=r.tenant, tier=r.tier)
-                out.append(Completed(req, res, queue_delay=res.queue_delay,
-                                     finished_at=now))
+                    continue
+                batch, ready = ready[: self.max_batch], ready[self.max_batch:]
+                if on_step is not None:
+                    on_step(group_no)
+                group_no += 1
+                admitted = now
+                t0 = time.perf_counter()
+                results = self.system.serve_batch(
+                    [r.prompt for r in batch],
+                    seeds=[r.seed for r in batch],
+                    quality_tiers=[r.quality_tier for r in batch])
+                now = admitted + (time.perf_counter() - t0)
+                for r, res in zip(batch, results):
+                    res.queue_delay = admitted - r.arrival_time
+                    req = Request(r.prompt, r.seed, r.quality_tier,
+                                  submitted_at=r.arrival_time,
+                                  tenant=r.tenant, tier=r.tier)
+                    out.append(Completed(req, res, queue_delay=res.queue_delay,
+                                         finished_at=now))
         self.completed.extend(out)
         return out
 
@@ -908,6 +936,7 @@ class ServingEngine:
         arr_of: Dict[int, TimedRequest] = {}
         admit_t: Dict[int, float] = {}
         img_ready: Dict[int, bool] = {}
+        ready_at: Dict[int, float] = {}   # engine clock: result ready
         alias_target: Dict[int, int] = {}
         inflight_gen: List[int] = []   # unfinalized gen handles, ascending
         next_handle = 0
@@ -927,37 +956,46 @@ class ServingEngine:
             admitted = now
             inflight = [(states[h].qvec, h) for h in inflight_gen]
             t0 = time.perf_counter()
-            planned = system.pipeline.run_admission(
-                system, [r.prompt for r in batch],
-                seeds=[r.seed for r in batch],
-                quality_tiers=[r.quality_tier for r in batch],
-                inflight=inflight or None)
-            for s, r in zip(planned, batch):
-                h = base + s.index
-                states[h], arr_of[h], admit_t[h] = s, r, admitted
-                if s.plan.kind == "gen":
-                    self._admit_with_retry(engine, s, h)
-                    inflight_gen.append(h)
-                    img_ready[h] = False
-                elif s.plan.kind == "alias":
-                    t = s.plan.target
-                    alias_target[h] = base + t if t >= 0 else -(t + 1)
+            with span("serve.admit", first_req=base, n=len(batch),
+                      free=free):
+                planned = system.pipeline.run_admission(
+                    system, [r.prompt for r in batch],
+                    seeds=[r.seed for r in batch],
+                    quality_tiers=[r.quality_tier for r in batch],
+                    inflight=inflight or None)
+                for s, r in zip(planned, batch):
+                    h = base + s.index
+                    states[h], arr_of[h], admit_t[h] = s, r, admitted
+                    if s.plan.kind == "gen":
+                        self._admit_with_retry(engine, s, h)
+                        inflight_gen.append(h)
+                        img_ready[h] = False
+                    elif s.plan.kind == "alias":
+                        t = s.plan.target
+                        alias_target[h] = base + t if t >= 0 else -(t + 1)
             next_handle += len(batch)
             now = admitted + (time.perf_counter() - t0)
+            for h in range(base, next_handle):
+                if states[h].plan.kind != "gen":
+                    ready_at[h] = now
 
         def finalize_due() -> None:
             nonlocal now, next_fin
             while next_fin < next_handle:
-                st = states[next_fin]
-                if st.plan.kind == "gen" and not img_ready[next_fin]:
+                h = next_fin
+                st = states[h]
+                if st.plan.kind == "gen" and not img_ready[h]:
                     break      # submission-order gate: wait for the slot
                 if st.plan.kind == "alias":
+                    # ready once its target's image is
+                    ready_at[h] = max(ready_at[h],
+                                      ready_at[alias_target[h]])
                     # target is an earlier gen request — already retired
                     # (and finalized) by the submission-order gate, so its
                     # image is available; this is the history fast path
                     # sequential serve takes once the target is recorded
                     st.plan = Plan(kind="history",
-                                   image=states[alias_target[next_fin]].image)
+                                   image=states[alias_target[h]].image)
                 elif st.plan.kind == "gen":
                     node = st.plan.node
                     if (0 <= node < len(system.dbs)
@@ -966,44 +1004,49 @@ class ServingEngine:
                                  if system.scheduler.nodes[i].alive]
                         if alive:   # reroute archive + accounting off the
                             st.plan.node = alive[0]   # dead node's VDB
+                # held by submission order alone since it was ready
+                wait = now - ready_at[h]
                 t0 = time.perf_counter()
-                system.pipeline.finalize(system, st)
+                with span("serve.finalize", req=h, release_wait=wait):
+                    system.pipeline.finalize(system, st)
                 now += time.perf_counter() - t0
-                r = arr_of[next_fin]
+                r = arr_of[h]
                 res = st.result
-                res.queue_delay = admit_t[next_fin] - r.arrival_time
+                res.queue_delay = admit_t[h] - r.arrival_time
                 req = Request(r.prompt, r.seed, r.quality_tier,
                               submitted_at=r.arrival_time,
                               tenant=r.tenant, tier=r.tier)
                 out.append(Completed(req, res, queue_delay=res.queue_delay,
-                                     finished_at=now))
-                if inflight_gen and inflight_gen[0] == next_fin:
+                                     finished_at=now, release_wait=wait))
+                if inflight_gen and inflight_gen[0] == h:
                     inflight_gen.pop(0)
                 next_fin += 1
 
-        while pending or ready or next_fin < next_handle:
-            admit_arrived()
-            if ready and engine.free_count() > 0:
-                do_admission()
-                finalize_due()     # cached/history/alias complete at once
-            if engine.active_count() > 0:
-                if on_step is not None:
-                    on_step(step_no)
-                self.slot_occupancy.append(engine.active_count())
-                t0 = time.perf_counter()
-                retired = engine.step()
-                now += time.perf_counter() - t0
-                step_no += 1
-                for h, st in retired:
-                    st.stage_ts["Generate"] = time.perf_counter()
-                    img_ready[h] = True
-                finalize_due()
-            elif not ready:
-                finalize_due()
-                if pending:
-                    now = max(now, pending[0].arrival_time)
-                elif next_fin >= next_handle:
-                    break
+        with span("serve.run", requests=len(pending)):
+            while pending or ready or next_fin < next_handle:
+                admit_arrived()
+                if ready and engine.free_count() > 0:
+                    do_admission()
+                    finalize_due()   # cached/history/alias complete now
+                if engine.active_count() > 0:
+                    if on_step is not None:
+                        on_step(step_no)
+                    self.slot_occupancy.append(engine.active_count())
+                    t0 = time.perf_counter()
+                    retired = engine.step()
+                    now += time.perf_counter() - t0
+                    step_no += 1
+                    for h, st in retired:
+                        st.stage_ts["Generate"] = time.perf_counter()
+                        img_ready[h] = True
+                        ready_at[h] = now
+                    finalize_due()
+                elif not ready:
+                    finalize_due()
+                    if pending:
+                        now = max(now, pending[0].arrival_time)
+                    elif next_fin >= next_handle:
+                        break
         self.completed.extend(out)
         return out
 
@@ -1062,9 +1105,8 @@ def tenant_tier_stats(completed: Sequence[Completed],
 
     Groups tagged completions (requests whose ``tenant`` or ``tier`` is
     set) and reports, per group: ``n``, ``queue_delay_p50/p95``,
-    ``wall_p50/p95`` (per-request measured pipeline wall ``wall_total``,
-    falling back to the batch-amortised ``wall_latency`` when a caller
-    built results without stage timestamps) and ``e2e_p50/p95``
+    ``wall_p50/p95`` (per-request measured pipeline wall ``wall_total``)
+    and ``e2e_p50/p95``
     (queue delay + wall).  Untagged completions are skipped; fully
     untagged traffic returns ``{}``, which is the "don't print the
     table" signal the serve CLI keys on.
@@ -1078,8 +1120,7 @@ def tenant_tier_stats(completed: Sequence[Completed],
     for key in sorted(groups, key=lambda k: (str(k[0]), str(k[1]))):
         cs = groups[key]
         qd = np.array([c.queue_delay for c in cs])
-        wall = np.array([c.result.wall_total if c.result.wall_total > 0
-                         else c.result.wall_latency for c in cs])
+        wall = np.array([c.result.wall_total for c in cs])
         e2e = qd + wall
         out[key] = {
             "n": len(cs),

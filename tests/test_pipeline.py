@@ -1,5 +1,5 @@
 """Staged serve pipeline: stage structure, batch-first backend protocol,
-vectorized composite scoring, and batch-amortised wall-latency accounting.
+vectorized composite scoring, and per-request wall-time accounting.
 
 The batched-vs-sequential parity contract itself is pinned in
 ``test_batching.py``; this module covers the redesign's new surfaces.
@@ -14,7 +14,7 @@ import pytest
 from repro.core.pipeline import (CallableBackend, GenerationBackend,
                                  ServePipeline)
 from repro.core.policy import GenerationPolicy
-from repro.core.trace import RequestTrace
+from repro.core.trace import RequestTrace, TimedRequest
 from repro.launch.serve import build_system
 from repro.runtime.serving import ServingEngine
 
@@ -293,41 +293,38 @@ def test_diffusion_backend_is_a_generation_backend():
 
 
 # ---------------------------------------------------------------------------
-# batch-amortised wall latency (ServeStats.batch_wall_latencies)
+# per-request wall time: wall_total is the sum of the request's stage walls
 # ---------------------------------------------------------------------------
 
 
-def test_wall_latency_is_batch_amortised():
-    system, _, _, _ = build_system(n_nodes=2, corpus_n=60,
-                                   capacity_per_node=60, seed=0)
-    reqs = list(RequestTrace(seed=1).generate(8))
-    out = system.serve_batch([r.prompt for r in reqs], seeds=list(range(8)))
-    assert len(system.stats.batch_wall_latencies) == 1
-    total = system.stats.batch_wall_latencies[0]
-    assert total > 0
-    # every result reports the SAME amortised share, and shares sum back
-    # to the batch total (old behaviour: each result reported the whole
-    # batch's wall clock, inflating per-request latency by ~batch size)
-    for r in out:
-        assert r.wall_latency == pytest.approx(total / 8)
-    assert sum(r.wall_latency for r in out) == pytest.approx(total)
-    # a second micro-batch appends a second total
-    system.serve_batch([reqs[0].prompt], seeds=[99])
-    assert len(system.stats.batch_wall_latencies) == 2
-
-
-def test_engine_drain_records_one_total_per_microbatch():
+@pytest.mark.parametrize("step_level", [False, True],
+                         ids=["drain", "step_level"])
+def test_wall_total_is_the_sum_of_stage_walls(step_level):
+    """Every result's ``wall_total`` (admission to Finish) is the sum of
+    its own ``stage_walls``, over every stage in order, whether the queue
+    is drained in micro-batches or served step-level."""
     system, _, _, _ = build_system(n_nodes=2, corpus_n=60,
                                    capacity_per_node=60, seed=0)
     engine = ServingEngine(system, max_batch=4)
-    for i, r in enumerate(RequestTrace(seed=2).generate(10)):
-        engine.submit(r.prompt, seed=i)
-    engine.drain()
-    # 10 requests at max_batch=4 -> micro-batches of 4, 4, 2
-    assert len(system.stats.batch_wall_latencies) == 3
-    assert len(system.stats.wall_latencies) == 10
-    assert sum(system.stats.wall_latencies) == pytest.approx(
-        sum(system.stats.batch_wall_latencies))
+    reqs = list(RequestTrace(seed=2).generate(10))
+    if step_level:
+        arrivals = [TimedRequest(0.01 * i, r.prompt, seed=i,
+                                 quality_tier=r.quality_tier)
+                    for i, r in enumerate(reqs)]
+        done = engine.run(arrivals, step_level=True, slot_capacity=4)
+    else:
+        for i, r in enumerate(reqs):
+            engine.submit(r.prompt, seed=i)
+        done = engine.drain()
+    assert len(done) == 10
+    names = system.pipeline.stage_names
+    for c in done:
+        r = c.result
+        assert list(r.stage_walls) == names
+        assert all(w >= 0.0 for w in r.stage_walls.values())
+        assert r.wall_total > 0.0
+        assert sum(r.stage_walls.values()) == pytest.approx(r.wall_total,
+                                                            rel=1e-9)
 
 
 # ---------------------------------------------------------------------------
