@@ -6,9 +6,13 @@ farthest ("semantic outliers carry mixed concepts of limited reference
 value").  LRU / LFU / FIFO are implemented on the same interface as the
 paper's baselines (Fig. 19).
 
-All policies operate across the fleet of node VDBs at once, exactly like
-Algorithm 2: build one global list, sort by the policy key, pop until the
-total size fits ``C_max``.
+All policies operate across the fleet of node VDBs at once, as Algorithm
+2 does, but test the budget first: a fleet whose valid rows already fit
+``C_max`` is neither scored nor touched.  Only over budget are the valid
+rows of every node scored, ranked by the policy key in one stable
+descending sort (node-major, slot-ascending on ties), and the
+``total - C_max`` highest evicted.  The sweep reads the node VDBs and
+changes them only through ``evict_slots`` on its victims.
 
 Per-depth utility (the latent-depth cache): noised-latent entries and
 finished images compete under the SAME ``C_max``, but a deep latent is
@@ -21,7 +25,7 @@ entries in the fleet the scores are bit-identical to the undepthed sort.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Sequence
 
 import numpy as np
 
@@ -62,29 +66,29 @@ class EvictionPolicy:
                  ) -> Dict[int, np.ndarray]:
         """Algorithm 2: evict across all nodes until total size <= c_max.
 
-        Returns {node_index: evicted payload ids}.
+        Returns {node_index: evicted payload ids}, each node's ids in
+        eviction order.  A fleet within ``c_max`` is not scored.
         """
-        depth_norm = max((int(db.depth[db.valid].max(initial=-1))
-                          for db in dbs), default=-1)
-        entries: List[Tuple[float, int, int]] = []  # (score, node, slot)
-        total = 0
-        for ni, db in enumerate(dbs):
-            total += db.size
-            s = self.depth_scores(db, depth_norm)
-            for slot in np.flatnonzero(db.valid):
-                entries.append((float(s[slot]), ni, int(slot)))
+        total = sum(db.size for db in dbs)
         if total <= c_max:
             return {}
-        entries.sort(key=lambda e: e[0], reverse=True)  # farthest first
-        n_evict = total - c_max
-        doomed: Dict[int, List[int]] = {}
-        for score, ni, slot in entries[:n_evict]:
-            doomed.setdefault(ni, []).append(slot)
+        depth_norm = max((int(db.depth[db.valid].max(initial=-1))
+                          for db in dbs), default=-1)
+        slots = [np.flatnonzero(db.valid) for db in dbs]
+        scores = np.concatenate([self.depth_scores(db, depth_norm)[sl]
+                                 for db, sl in zip(dbs, slots)])
+        nodes = np.repeat(np.arange(len(dbs)), [len(sl) for sl in slots])
+        slots = np.concatenate(slots)
+        # highest score first; the stable sort keeps equal scores in
+        # (node, slot) order, as a stable sort of the (score, node, slot)
+        # list with reverse=True does
+        doomed = np.argsort(-scores, kind="stable")[:total - c_max]
+        victim_nodes = nodes[doomed]
         # one evict_slots call per node (one device validity update per
         # node when the db is a ClusterIndex view, not one per slot)
-        return {ni: dbs[ni].evict_slots(np.array(slots, np.int64))
-                          .astype(np.int64)
-                for ni, slots in doomed.items()}
+        return {int(ni): dbs[ni].evict_slots(
+                    slots[doomed[victim_nodes == ni]]).astype(np.int64)
+                for ni in np.unique(victim_nodes)}
 
 
 class LCUPolicy(EvictionPolicy):
@@ -93,9 +97,13 @@ class LCUPolicy(EvictionPolicy):
     name = "LCU"
 
     def scores(self, db: VectorDB) -> np.ndarray:
-        mu = db.centroid()
-        d = np.linalg.norm(db.img_vecs - mu[None, :], axis=-1)
-        return np.where(db.valid, d, -np.inf)
+        # distances of the valid rows only, into a new array: the rows
+        # themselves are the device slabs' source of truth
+        live = np.flatnonzero(db.valid)
+        s = np.full((db.capacity,), -np.inf, np.float32)
+        s[live] = np.linalg.norm(db.img_vecs[live] - db.centroid()[None, :],
+                                 axis=-1)
+        return s
 
 
 class LRUPolicy(EvictionPolicy):

@@ -342,9 +342,14 @@ class CacheGenius:
 
     def maintain(self) -> Dict[int, np.ndarray]:
         """Run the eviction policy across all node VDBs (Algorithm 2), as
-        one ``maintain`` span: ``rows`` is the valid slots the sweep
-        visits, ``evicted`` the slots it frees."""
-        with span("maintain", rows=self.total_size) as sp:
+        one ``maintain`` span: ``rows`` is the fleet's valid slots,
+        ``scored`` those the policy scored (0 when the fleet is within
+        ``cache_capacity``), ``evicted`` the slots it frees."""
+        rows = self.total_size
+        # the policy scores the fleet's rows only when they exceed the
+        # budget (core/lcu.py)
+        scored = rows if rows > self.cache_capacity else 0
+        with span("maintain", rows=rows, scored=scored) as sp:
             evicted = self.eviction.maintain(self.dbs, self.cache_capacity)
             all_payloads = []
             for _, payloads in evicted.items():

@@ -341,6 +341,174 @@ def test_maintain_keeps_history_cache_consistent():
         system.serve(r.prompt, seed=1000 + i)
 
 
+# every per-slot column of a VectorDB (the rows and their metadata)
+_COLUMNS = ("img_vecs", "txt_vecs", "valid", "payload_ids", "depth",
+            "source_id", "insert_time", "last_access", "access_count")
+
+
+def _columns(db):
+    return {c: getattr(db, c).copy() for c in _COLUMNS}
+
+
+def _assert_device_matches_host(ci):
+    """The ClusterIndex slabs and validity equal the numpy rows they are
+    built from: the agreement the benchmark's scan check relies on."""
+    slabs, valid = ci.device_state()
+    want_slabs, want_valid = ci.rebuild_reference()
+    np.testing.assert_array_equal(slabs, want_slabs)
+    np.testing.assert_array_equal(valid, want_valid)
+
+
+def _per_row_sweep(policy, dbs, c_max):
+    """Reference: the per-row sweep the vectorised one replaced. One
+    (score, node, slot) tuple per valid row, a stable sort highest score
+    first, then one ``evict_slots`` per node."""
+    depth_norm = max((int(db.depth[db.valid].max(initial=-1))
+                      for db in dbs), default=-1)
+    entries = []
+    total = 0
+    for ni, db in enumerate(dbs):
+        total += db.size
+        s = policy.depth_scores(db, depth_norm)
+        for slot in np.flatnonzero(db.valid):
+            entries.append((float(s[slot]), ni, int(slot)))
+    if total <= c_max:
+        return {}
+    entries.sort(key=lambda e: e[0], reverse=True)
+    doomed = {}
+    for _, ni, slot in entries[:total - c_max]:
+        doomed.setdefault(ni, []).append(slot)
+    return {ni: dbs[ni].evict_slots(np.array(slots, np.int64))
+            .astype(np.int64)
+            for ni, slots in doomed.items()}
+
+
+def _tied_fleet(latent: bool):
+    """Three nodes whose rows tie under every policy's key: each node
+    holds four groups of three identical vectors (equal LCU distances),
+    inserted in two batches at shared clocks (FIFO, LRU, LFU ties across
+    nodes too), with some rows used again and one hole in the slots.
+    With ``latent`` each group is a latent depth of its own."""
+    rng = np.random.default_rng(21)
+    dbs = []
+    for ni in range(3):
+        db = VectorDB(8, 24)
+        vecs = np.repeat(_unit(rng, 4, 8), 3, axis=0)
+        pids = np.arange(12, dtype=np.int64) + 100 * ni
+        depths = (np.repeat([-1, 2, 4, 6], 3) if latent
+                  else np.full(12, -1))
+        for lo, hi, t in ((0, 6, 1.0), (6, 12, 2.0)):
+            db.add(vecs[lo:hi], vecs[lo:hi], pids[lo:hi], t=t,
+                   depths=depths[lo:hi])
+        db.mark_access(np.array([0, 4, 8]), t=3.0)
+        db.evict_slots(np.array([5]))
+        dbs.append(db)
+    return dbs
+
+
+def _clone(db):
+    new = VectorDB.restore(db.dim, db.capacity, db.snapshot())
+    # the running centroid, bit for bit (a rebuild sums in another order)
+    new._cent_sum, new._cent_count = db._cent_sum.copy(), db._cent_count
+    return new
+
+
+def _tie_splitting_budget(policy, dbs):
+    """A ``c_max`` whose cut falls between two rows of equal score."""
+    depth_norm = max(int(db.depth[db.valid].max(initial=-1)) for db in dbs)
+    ranked = sorted((float(s) for db in dbs
+                     for s in policy.depth_scores(db, depth_norm)[db.valid]),
+                    reverse=True)
+    cuts = [k for k in range(1, len(ranked)) if ranked[k - 1] == ranked[k]]
+    assert cuts, "the fleet has no tie to split"
+    return len(ranked) - cuts[len(cuts) // 2]
+
+
+@pytest.mark.parametrize("latent", [False, True],
+                         ids=["images", "latents"])
+@pytest.mark.parametrize("policy", sorted(POLICIES))
+def test_sweep_picks_the_per_row_loops_victims(policy, latent):
+    """Over budget, the vectorised sweep evicts exactly what the per-row
+    loop did, node for node and in the same order, also where the cut
+    splits a tie; it writes no row, and the device slabs still equal the
+    host rows after it and after a following add."""
+    from repro.core.cluster_index import ClusterIndex
+
+    pol = POLICIES[policy]
+    dbs = _tied_fleet(latent)
+    ref_dbs = [_clone(db) for db in dbs]
+    ci = ClusterIndex.from_dbs(dbs)
+    c_max = _tie_splitting_budget(pol, dbs)
+    vecs = [(db.img_vecs.copy(), db.txt_vecs.copy()) for db in dbs]
+
+    got = pol.maintain(dbs, c_max)
+    want = _per_row_sweep(pol, ref_dbs, c_max)
+    assert {n: v.tolist() for n, v in got.items()} == {
+        n: v.tolist() for n, v in want.items()}
+    assert len(got) > 1                        # victims on several nodes
+    assert sum(db.size for db in dbs) == c_max
+    for db, ref, (img, txt) in zip(dbs, ref_dbs, vecs):
+        for c in _COLUMNS:
+            np.testing.assert_array_equal(getattr(db, c), getattr(ref, c))
+        assert db.img_vecs.tobytes() == img.tobytes()
+        assert db.txt_vecs.tobytes() == txt.tobytes()
+    _assert_device_matches_host(ci)
+
+    freed = np.flatnonzero(~dbs[1].valid)[:2]
+    new = _unit(np.random.default_rng(5), 2, 8)
+    slots = dbs[1].add(new, new, np.array([900, 901]), t=4.0)
+    np.testing.assert_array_equal(slots, freed)
+    _assert_device_matches_host(ci)
+
+
+def test_under_budget_sweep_scores_nothing_and_changes_nothing(monkeypatch):
+    """A fleet within its budget is neither scored nor touched: every
+    row, metadata column and device slab is bit-identical after the
+    sweep, and the device slabs still equal the host rows."""
+    from repro.launch.serve import build_system
+
+    system, _, _, _ = build_system(n_nodes=2, corpus_n=40,
+                                   capacity_per_node=40, seed=0)
+    assert system.cluster_index is not None
+    assert 0 < system.total_size <= system.cache_capacity
+    calls = []
+    scores = system.eviction.scores
+
+    def spy(db):
+        calls.append(db)
+        return scores(db)
+    monkeypatch.setattr(system.eviction, "scores", spy)
+    before = [_columns(db) for db in system.dbs]
+    slabs, valid = system.cluster_index.device_state()
+
+    assert system.maintain() == {}
+    assert calls == []
+    for db, cols in zip(system.dbs, before):
+        for c, v in cols.items():
+            assert getattr(db, c).tobytes() == v.tobytes(), c
+    after = system.cluster_index.device_state()
+    assert after[0].tobytes() == slabs.tobytes()
+    assert after[1].tobytes() == valid.tobytes()
+    _assert_device_matches_host(system.cluster_index)
+
+
+def test_lcu_scores_equal_the_full_slab_distances():
+    """LCU scores only the valid rows, into a new array: the same float32
+    distances as over the whole slab, -inf elsewhere, rows untouched."""
+    rng = np.random.default_rng(9)
+    db = _db_with(rng, n=12, d=512)
+    db.evict_slots(np.array([3, 7]))
+    img = db.img_vecs.copy()
+    mu = db.centroid()
+    want = np.where(db.valid,
+                    np.linalg.norm(db.img_vecs - mu[None, :], axis=-1),
+                    -np.inf)
+    got = LCUPolicy().scores(db)
+    assert got.dtype == want.dtype
+    assert got.tobytes() == want.tobytes()
+    assert db.img_vecs.tobytes() == img.tobytes()
+
+
 def test_blob_store_consistency():
     blob = BlobStore()
     a = blob.put(np.ones((2, 2)))

@@ -161,6 +161,32 @@ def test_maintain_counts_rows_and_evictions(traced_run):
         assert sp[3]["rows"] > 0 and sp[3]["evicted"] >= 0
 
 
+def test_maintain_reports_rows_scored(traced_run):
+    """Every sweep carries ``scored``; the run's fleet stays within its
+    budget, so no sweep scores a row or evicts one."""
+    capacity = traced_run["system"].cache_capacity
+    sweeps = [sp for sp in traced_run["spans"] if sp[0] == "maintain"]
+    assert sweeps
+    for sp in sweeps:
+        assert sp[3]["rows"] <= capacity
+        assert sp[3]["scored"] == 0 and sp[3]["evicted"] == 0
+
+
+def test_over_budget_sweep_reports_every_row_scored(tmp_path):
+    system, _, _, _ = build_system(n_nodes=2, corpus_n=40,
+                                   capacity_per_node=40, seed=0)
+    rows = system.total_size
+    system.cache_capacity = rows - 3
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        system.maintain()
+    finally:
+        jax.profiler.stop_trace()
+    (_, _, _, stats), = [sp for sp in _load_spans(str(tmp_path))
+                         if sp[0] == "maintain"]
+    assert stats == {"rows": rows, "scored": rows, "evicted": 3}
+
+
 def test_one_request_carries_one_req(traced_run):
     spans = traced_run["spans"]
     by_name = defaultdict(list)
